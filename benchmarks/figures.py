@@ -49,6 +49,16 @@ if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
 
+def _cpu_child_env() -> Dict[str, str]:
+    """Environment of a forced-host-device subprocess leg: pinned to the CPU
+    (JAX_PLATFORMS=cpu) so the child never reaches for an accelerator that
+    this process holds."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = _SRC
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def _engine(n, method, seed=42, speedup=100.0, depth=None, edge_capacity=64):
     import jax
     from repro.core.engine import EngineConfig, PlasticityEngine
@@ -156,8 +166,7 @@ def fig4_weak_scaling(device_counts=(1, 2, 4, 8), n_per=512) -> Dict:
     """Fixed n/device, growing device count (forced host devices; wall time
     includes the simulated collectives — host CPU stands in for the fabric)."""
     out = {}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC
+    env = _cpu_child_env()
     for p in device_counts:
         res = subprocess.run(
             [sys.executable, "-c", _WEAK_SCRIPT, str(p), str(n_per)],
@@ -333,8 +342,7 @@ def fig_sweep2d(ensemble=2, data=2, n=128, k=2, steps=400) -> Dict:
     CPU "devices" share two cores, so the mesh time measures collective
     overhead rather than speedup; on real multi-chip hosts the same program
     scales in both K and n."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC
+    env = _cpu_child_env()
     res = subprocess.run(
         [sys.executable, "-c", _SWEEP2D_SCRIPT, str(ensemble * data),
          str(ensemble), str(data), str(n), str(k), str(steps)],
@@ -355,8 +363,7 @@ from repro.core.engine import EngineConfig, PlasticityEngine
 from repro.core.msp import MSPConfig
 from repro.core.traversal import FMMConfig
 from repro.launch.mesh import make_data_mesh
-from repro.sharding.rules import (SHARD_MAP_NO_CHECK, pyramid_input_spec,
-                                  shard_map)
+from repro.sharding.rules import pyramid_input_spec
 
 p, n, reps, depth = (int(a) for a in sys.argv[1:5])
 rng = np.random.default_rng(0)
@@ -379,9 +386,10 @@ for mode in ("owner_span", "masked"):
         out["span_widths"] = [int(w) for w in eng._spans.width]
         out["shardable_elements_per_device"] = \
             eng._spans.shardable_elements_per_device
-    fn = jax.jit(shard_map(lambda a, d: eng._local_pyramid(a, d), mesh=mesh,
-                           in_specs=(pyramid_input_spec(),) * 2,
-                           out_specs=P(), **SHARD_MAP_NO_CHECK))
+    fn = jax.jit(jax.shard_map(lambda a, d: eng._local_pyramid(a, d),
+                               mesh=mesh,
+                               in_specs=(pyramid_input_spec(),) * 2,
+                               out_specs=P(), check_vma=False))
     got = fn(ax, den)
     bitwise = all(
         np.array_equal(np.asarray(getattr(a, nm)), np.asarray(getattr(b, nm)))
@@ -416,8 +424,7 @@ def fig_pyramid_scaling(device_counts=(1, 2, 4, 8), n=2048, reps=3,
     scaling ~1/p, plus a bitwise-parity canary for BOTH modes against the
     single-device `octree.build_pyramid`.  Wall times are informational only
     on CI hosts (the forced devices share two cores)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC
+    env = _cpu_child_env()
     out: Dict = {}
     for p in device_counts:
         res = subprocess.run(
@@ -458,7 +465,6 @@ from repro.core.engine import EngineConfig, PlasticityEngine
 from repro.core.msp import MSPConfig
 from repro.core.traversal import FMMConfig
 from repro.launch.mesh import make_data_mesh
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 p, n, steps, reps, depth = (int(a) for a in sys.argv[1:6])
 rng = np.random.default_rng(0)
@@ -489,10 +495,10 @@ for phase in ("sharded", "replicated"):
     state = state._replace(neurons=state.neurons._replace(
         ax_elems=jnp.full((n,), 2.0), den_elems=jnp.full((n,), 2.0)))
     state_spec, rec_spec = eng._specs()
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         lambda s, k: eng.local_step(s, k, do_update=jnp.bool_(True)),
         mesh=mesh, in_specs=(state_spec, P()),
-        out_specs=(state_spec, rec_spec), **SHARD_MAP_NO_CHECK))
+        out_specs=(state_spec, rec_spec), check_vma=False))
     jax.block_until_ready(step(state, jax.random.key(0))[0].edges.valid)
     ts = []
     for r in range(reps):
@@ -519,8 +525,7 @@ def fig_find_scaling(device_counts=(1, 2, 4, 8), n=2048, steps=800,
     both phases reproduce single-device `simulate` exactly.  Wall times of
     one connectivity-update step are informational on CI hosts (the forced
     devices share two cores)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC
+    env = _cpu_child_env()
     out: Dict = {}
     for p in device_counts:
         res = subprocess.run(
@@ -563,7 +568,6 @@ from repro.core.ensemble import EnsembleEngine
 from repro.core.msp import MSPConfig
 from repro.core.traversal import FMMConfig
 from repro.launch import sweep
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 p, n, steps, depth, sweep_k, reps = (int(a) for a in sys.argv[1:7])
 rng = np.random.default_rng(0)
@@ -600,10 +604,10 @@ for mode in ("routed", "gathered"):
     state = state._replace(neurons=state.neurons._replace(
         ax_elems=jnp.full((n,), 2.0), den_elems=jnp.full((n,), 2.0)))
     state_spec, rec_spec = eng._specs()
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         lambda s, k: eng.local_step(s, k, do_update=jnp.bool_(True)),
         mesh=mesh, in_specs=(state_spec, P()),
-        out_specs=(state_spec, rec_spec), **SHARD_MAP_NO_CHECK))
+        out_specs=(state_spec, rec_spec), check_vma=False))
     jax.block_until_ready(step(state, jax.random.key(0))[0].edges.valid)
     ts = []
     for r in range(reps):
@@ -693,8 +697,7 @@ def fig_exchange(device_counts=(1, 2, 4, 8), n=128, steps=1500, depth=3,
         weak[str(weak_counts[-1])]["gathered_payload_elements"]
         / base["gathered_payload_elements"], 4)
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC
+    env = _cpu_child_env()
     for p in device_counts:
         res = subprocess.run(
             [sys.executable, "-c", _EXCHANGE_SCRIPT, str(p), str(n),

@@ -26,6 +26,7 @@ import sys
 import time
 
 from benchmarks import figures
+from repro.launch import compile_cache
 
 # CI-smoke sizes per figure (--quick).  Keys match the run() names below.
 QUICK = {
@@ -50,6 +51,7 @@ QUICK = {
 
 
 def main() -> None:
+    compile_cache.enable()
     args = sys.argv[1:]
     quick = "--quick" in args
     pr_id = None

@@ -81,14 +81,12 @@ def bad_r2_replica_psum() -> EntrySpec:
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: jax.lax.psum(x, "ensemble"),
             mesh=_mesh(),
             in_specs=P("ensemble"),
             out_specs=P(),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return fn, (jnp.ones((4,), jnp.float32),)
 
@@ -105,14 +103,12 @@ def bad_r2_out_of_scope_gather() -> EntrySpec:
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: jax.lax.all_gather(x, "data", tiled=True),
             mesh=_mesh(),
             in_specs=P("data"),
             out_specs=P(),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return fn, (jnp.ones((4,), jnp.float32),)
 
@@ -127,14 +123,12 @@ def good_r2_data_psum() -> EntrySpec:
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda x: jax.lax.psum(x, "data"),
             mesh=_mesh(),
             in_specs=P(None, "data"),
             out_specs=P(),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return fn, (jnp.ones((1, 4), jnp.float32),)
 
@@ -163,17 +157,15 @@ def bad_r3_select_gather() -> EntrySpec:
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
         def one(pred, x):
             return jax.lax.cond(pred, _gather_branch, lambda x: jnp.float32(0), x)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             jax.vmap(one),
             mesh=_mesh(),
             in_specs=(P(), P(None, "data")),
             out_specs=P(),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         preds = jnp.zeros((2,), bool)
         xs = jnp.ones((2, _E), jnp.float32)
@@ -189,8 +181,6 @@ def good_r3_reduced_predicate() -> EntrySpec:
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
         def batched(preds, xs):
             return jax.lax.cond(
                 jnp.any(preds),
@@ -199,12 +189,12 @@ def good_r3_reduced_predicate() -> EntrySpec:
                 xs,
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             batched,
             mesh=_mesh(),
             in_specs=(P(), P(None, "data")),
             out_specs=P(),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         preds = jnp.zeros((2,), bool)
         xs = jnp.ones((2, _E), jnp.float32)

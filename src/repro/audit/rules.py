@@ -14,7 +14,7 @@ from __future__ import annotations
 
 from typing import Any, Mapping
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.audit import walker
 from repro.audit.report import Finding

@@ -171,8 +171,6 @@ def _build_dist_update_vmapped():
     def build():
         from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
-
         eng = _dist_engine("fmm", "sharded", "gathered")
         states = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (_K,) + x.shape), eng.init_state()
@@ -186,12 +184,12 @@ def _build_dist_update_vmapped():
 
         state_spec, _ = eng._specs()
         bspec = jax.tree.map(lambda s: P(None, *s), state_spec)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             batched_update,
             mesh=eng.mesh,
             in_specs=(bspec, P()),
             out_specs=bspec,
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return sharded, (states, keys)
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Iterator
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 try:  # diagnostics only; private module, tolerate relocation
     from jax._src import source_info_util as _src_info

@@ -69,7 +69,6 @@ from jax import custom_batching
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.sharding import rules
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 from repro.core import barnes_hut, msp, octree, synapses, traversal
 from repro.core import multi_index as mi
@@ -681,10 +680,10 @@ class DistributedPlasticityEngine(PlasticityEngine):
     def make_sharded_step(self):
         """Returns a jitted sharded step: (state, key) -> (state, record)."""
         state_spec, rec_spec = self._specs()
-        sharded = shard_map(lambda s, k: self.local_step(s, k),
-                            mesh=self.mesh, in_specs=(state_spec, P()),
-                            out_specs=(state_spec, rec_spec),
-                            **SHARD_MAP_NO_CHECK)
+        sharded = jax.shard_map(lambda s, k: self.local_step(s, k),
+                                mesh=self.mesh, in_specs=(state_spec, P()),
+                                out_specs=(state_spec, rec_spec),
+                                check_vma=False)
         return jax.jit(sharded)
 
     @functools.partial(jax.jit, static_argnums=(0, 3, 5))
@@ -725,11 +724,11 @@ class DistributedPlasticityEngine(PlasticityEngine):
                 body, (st, ps), jnp.arange(num_steps, dtype=jnp.int32))
             return st, ps, recs
 
-        sharded = shard_map(local_sim, mesh=self.mesh,
-                            in_specs=(state_spec, P(), param_spec,
-                                      probe_spec),
-                            out_specs=(state_spec, probe_spec, rec_spec),
-                            **SHARD_MAP_NO_CHECK)
+        sharded = jax.shard_map(local_sim, mesh=self.mesh,
+                                in_specs=(state_spec, P(), param_spec,
+                                          probe_spec),
+                                out_specs=(state_spec, probe_spec, rec_spec),
+                                check_vma=False)
         state, probe_state, recs = sharded(state, key, params, probe_state)
         if probes is None:
             return state, recs
@@ -830,7 +829,7 @@ class DistributedEnsembleEngine:
         step_fn = lambda s, key, pr, upd: eng.local_step(
             s, key, do_update=upd, params=pr)
         merge = lambda x: jax.lax.psum(x, eng.axis)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda st, ks, pr, ps: scan_replicas(
                 step_fn, st, ks, pr, num_steps, eng.msp_cfg.update_interval,
                 probes=probes, probe_states=ps, merge=merge),
@@ -838,7 +837,7 @@ class DistributedEnsembleEngine:
             in_specs=(state_spec, P(self.ensemble_axis), param_spec,
                       probe_spec),
             out_specs=(state_spec, probe_spec, rec_spec),
-            **SHARD_MAP_NO_CHECK)
+            check_vma=False)
         states, probe_states, recs = sharded(states, keys, params,
                                              probe_states)
         if probes is None:

@@ -103,8 +103,8 @@ class EngineConfig:
     pyramid: str = "segsum"
     # Numeric backend of the evaluation hot spots (DESIGN.md §11):
     # "reference" = pure-jnp paths; "pallas" = the kernels/ Pallas kernels
-    # (interpret mode off-TPU, so CPU runs stay exact-but-slow); "auto" =
-    # Pallas on TPU, reference elsewhere.  Composes with `method`: the fused
+    # (interpret mode on CPU, so CPU runs stay exact-but-slow); "auto" =
+    # Pallas on TPU, reference on CPU.  Composes with `method`: the fused
     # neuron update routes on every method, the M2L kernel on method="fmm".
     backend: str = "reference"
     # RNG stream layout (DESIGN.md §14): "batched" = one vectorised draw per

@@ -42,7 +42,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.engine import (KernelParams, PlasticityEngine, SimState,
                                StepRecord)
 from repro.sharding import rules
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 
 def scan_replicas(step_fn, states: SimState, keys: jax.Array,
@@ -211,13 +210,13 @@ class EnsembleEngine:
             probe_spec = rules.ensemble_spec(probe_states, self.axis)
             rec_spec = StepRecord(*(P(None, self.axis),)
                                   * len(StepRecord._fields))
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 lambda st, k, pr, ps: self._sim(st, k, pr, num_steps,
                                                 probes, ps),
                 mesh=self.mesh,
                 in_specs=(state_spec, P(self.axis), param_spec, probe_spec),
                 out_specs=(state_spec, probe_spec, rec_spec),
-                **SHARD_MAP_NO_CHECK)
+                check_vma=False)
             states, probe_states, recs = sharded(states, keys, params,
                                                  probe_states)
         if probes is None:

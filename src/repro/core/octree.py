@@ -45,7 +45,6 @@ from typing import List, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import custom_batching
 
 from repro.core import expansions as ex
 from repro.core import multi_index as mi
@@ -164,19 +163,6 @@ def build_structure(positions: np.ndarray, domain: float,
 # Per-update dynamic data (jittable)
 # ---------------------------------------------------------------------------
 
-@custom_batching.custom_vmap
-def _pin(v: jnp.ndarray) -> jnp.ndarray:
-    """optimization_barrier with a vmap rule (jax 0.4.x has none built in):
-    the ensemble path vmaps the distributed step over replicas, and the
-    barrier must survive batching for the level build to stay fusion-stable."""
-    return jax.lax.optimization_barrier(v)
-
-
-@_pin.def_vmap
-def _pin_vmap(axis_size, in_batched, v):
-    return jax.lax.optimization_barrier(v), in_batched[0]
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class LevelData:
@@ -223,6 +209,7 @@ def build_level_raw(box_ids: jnp.ndarray, num_boxes: int, centers: jnp.ndarray,
     Returns (den_w, ax_w, den_pos, ax_pos, herm_raw, moms).
     """
     seg = lambda vals: jax.ops.segment_sum(vals, box_ids, num_segments=num_boxes)
+    pin = jax.lax.optimization_barrier
     # The optimization_barrier pins the weighted payloads as materialised
     # values so the scatter-add consumes identically rounded update rows in
     # EVERY surrounding program.  Without it XLA is free to fuse (and
@@ -232,16 +219,16 @@ def build_level_raw(box_ids: jnp.ndarray, num_boxes: int, centers: jnp.ndarray,
     # there and in a plain jit on one device, and both must round alike.
     den_w = seg(den_vac)
     ax_w = seg(ax_vac)
-    den_pos = seg(_pin(den_vac[:, None] * positions))
-    ax_pos = seg(_pin(ax_vac[:, None] * positions))
+    den_pos = seg(pin(den_vac[:, None] * positions))
+    ax_pos = seg(pin(ax_vac[:, None] * positions))
 
     scaled = (positions - centers[box_ids]) / jnp.sqrt(delta)
     feats = mi.monomials(scaled, p)                       # (n, k)
     # A_alpha(B) = 1/alpha! sum_{j in B} den_j ((s_j - gc_B)/sqrt(delta))^alpha
     # (the 1/alpha! is applied in finalize_level, AFTER any cross-device merge)
-    herm_raw = seg(_pin(den_vac[:, None] * feats))
+    herm_raw = seg(pin(den_vac[:, None] * feats))
     # M_beta(B) = sum_{i in B} ax_i ((t_i - gc_B)/sqrt(delta))^beta
-    moms = seg(_pin(ax_vac[:, None] * feats))
+    moms = seg(pin(ax_vac[:, None] * feats))
     return den_w, ax_w, den_pos, ax_pos, herm_raw, moms
 
 

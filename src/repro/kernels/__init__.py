@@ -14,22 +14,16 @@ semantics (tests/test_kernels.py sweeps shapes against it):
 
 Dispatch contract (`ops.py`): every wrapper takes `use_pallas` —
 
-    None  (auto)  -> Pallas on TPU, the `ref.py` reference elsewhere;
-    True  (force) -> Pallas; off-TPU this sets `interpret=True`, running the
-                     kernel body in Python per grid step — exact same
-                     numerics as the TPU lowering, so CPU CI can gate parity;
+    None  (auto)  -> Pallas on TPU, the `ref.py` reference on CPU;
+    True  (force) -> Pallas; on CPU this sets `interpret=True`, running the
+                     kernel body in Python per grid step, so CPU CI can gate
+                     parity against the oracle;
     False (off)   -> the reference, everywhere.
+
+Any backend other than TPU and CPU raises instead of falling back.
 
 Engine plumbing maps `EngineConfig.backend` ("reference"/"pallas"/"auto")
 onto this flag via `ops.use_pallas_flag`; core modules import `ops` lazily so
 the reference path never touches Pallas machinery.
 """
 
-
-def tpu_compiler_params(**kwargs):
-    """Version shim: pltpu.CompilerParams (jax >= 0.5) was TPUCompilerParams
-    in 0.4.x.  Kernel modules route through this so both resolve."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

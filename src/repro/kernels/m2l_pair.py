@@ -11,12 +11,12 @@ the kernel computes, per pair, three (p x p) Hankel matrices from the per-dim
 Hermite-polynomial recurrence and applies three mode products — O(3 p^4)
 instead of the dense O(p^6) (see expansions.box_mass_taylor_log).
 
-TPU layout notes: the pair axis is the parallel/sublane axis; coefficient
-tensors stay (BP, 64) with the 64-coefficient axis on lanes (50% lane
-utilisation at p=4 — acceptable because the kernel is VPU-bound and the pair
-axis supplies the parallelism).  The mode products are unrolled as 4
-lane-slices each, keeping everything as (BP, 16)-shaped vector FMAs with no
-gather/scatter inside the kernel.
+TPU layout: the PAIR axis fills whole vector registers.  The wrapper lays
+the coefficient tensors out as (k, 8, B/8) — coefficient index leading,
+pairs folded onto 8 sublanes x B/8 lanes — so every coefficient of a block
+is one dense (8, L) tile, read by an index on the untiled leading axis.
+The mode products are then unrolled over the coefficient indices as
+(8, L)-tile FMAs: no reshape, lane shuffle or gather inside the kernel.
 """
 from __future__ import annotations
 
@@ -26,103 +26,89 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro import kernels
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import multi_index as mi
 
-DEFAULT_BP = 512
+DEFAULT_BP = 2048          # pairs per block: 8 sublanes x 256 lanes
 P = 4                      # expansion order per dim (paper: alpha <= (3,3,3))
 K = P ** 3
 
 
 def _kernel(moms_ref, herm_ref, y_ref, out_ref, *, p: int):
     big_p = 2 * p - 1
+    k = p ** 3
+    shape = (p, p, p)
     # moms arrives pre-divided by beta!, herm pre-multiplied by sign_alpha
     # (folded in by the wrapper so the kernel captures no constants).
-    t = moms_ref[...]                                  # (BP, k) = (b1 b2 b3)
-    a = herm_ref[...]                                  # (BP, k)
-    y = y_ref[...]                                     # (BP, 8); cols 0..2 used
+    t = [moms_ref[c] for c in range(k)]                # k x (8, L), (b1 b2 b3)
 
     # Per-dim Hermite polynomials H_0..H_{2p-2} of y_d, by recurrence.
     hs = []
     for d in range(3):
-        yd = y[:, d]                                   # (BP,)
+        yd = y_ref[d]                                  # (8, L)
         cols = [jnp.ones_like(yd)]
         if big_p > 1:
             cols.append(2.0 * yd)
         for nn in range(1, big_p - 1):
             cols.append(2.0 * yd * cols[-1] - 2.0 * nn * cols[-2])
-        hs.append(cols)                                # list of (BP,)
+        hs.append(cols)
 
-    # Three mode products, unrolled over the small p axis.  Index layout of
-    # the flat coefficient axis is row-major (n1, n2, n3).
+    # Three mode products over the row-major (n1, n2, n3) coefficient index:
+    # contract index `dim` with G[a, b] = H_{a+b}(y_dim), writing a in place.
     def mode_product(tensor, dim, cols):
-        # tensor: (BP, k) flat over (i1, i2, i3); contract index `dim` with
-        # G[a, b] = H_{a+b}(y_dim), writing index a in its place.
-        out_slices = []
-        for a_i in range(p):
+        out = []
+        for flat in range(k):
+            idx = list(np.unravel_index(flat, shape))
+            a_i = idx[dim]
             acc = None
             for b_i in range(p):
-                g = cols[a_i + b_i][:, None]           # (BP, 1)
-                sl = _take_dim(tensor, dim, b_i, p)    # (BP, p*p)
-                term = g * sl
+                idx[dim] = b_i
+                term = cols[a_i + b_i] * tensor[np.ravel_multi_index(idx,
+                                                                     shape)]
                 acc = term if acc is None else acc + term
-            out_slices.append(acc)
-        return _stack_dim(out_slices, dim, p)          # (BP, k)
+            out.append(acc)
+        return out
 
     for d in range(3):
         t = mode_product(t, d, hs[d])
 
-    out_ref[...] = jnp.sum(a * t, axis=-1)
-
-
-def _take_dim(flat, dim, idx, p):
-    """Slice index `idx` of dimension `dim` from a (BP, p^3) row-major flat
-    tensor -> (BP, p^2)."""
-    bp = flat.shape[0]
-    t = flat.reshape(bp, p, p, p)
-    if dim == 0:
-        return t[:, idx].reshape(bp, p * p)
-    if dim == 1:
-        return t[:, :, idx].reshape(bp, p * p)
-    return t[:, :, :, idx].reshape(bp, p * p)
-
-
-def _stack_dim(slices, dim, p):
-    """Inverse of _take_dim: stack p (BP, p^2) slices into (BP, p^3)."""
-    bp = slices[0].shape[0]
-    t = jnp.stack([s.reshape(bp, p, p) for s in slices], axis=dim + 1)
-    return t.reshape(bp, p ** 3)
+    acc = herm_ref[0] * t[0]
+    for c in range(1, k):
+        acc = acc + herm_ref[c] * t[c]
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("p", "bp", "interpret"))
 def m2l_separable(moms: jnp.ndarray, herm: jnp.ndarray, y: jnp.ndarray,
                   p: int = P, bp: int = DEFAULT_BP,
                   interpret: bool = False) -> jnp.ndarray:
-    """Batched separable M2L series.  moms/herm (B, p^3), y (B, 3) -> (B,)."""
+    """Batched separable M2L series.  moms/herm (B, p^3), y (B, 3) -> (B,).
+
+    bp: pairs per grid step, a multiple of 1024 (8 sublanes x 128 lanes).
+    """
     b = moms.shape[0]
     bpad = ((b + bp - 1) // bp) * bp
     k = p ** 3
     fact = jnp.asarray(np.asarray(mi.multi_factorial(p), np.float32))
     sign = jnp.asarray(np.asarray(mi.sign_table(p), np.float32))
-    moms = moms.astype(jnp.float32) / fact
-    herm = herm.astype(jnp.float32) * sign
-    pad2 = lambda x: jnp.pad(x, ((0, bpad - b), (0, 0)))
-    y8 = jnp.pad(y.astype(jnp.float32), ((0, bpad - b), (0, 8 - y.shape[1])))
+    # (B, c) -> (c, 8, B/8): coefficient-major, pairs on sublanes x lanes.
+    tiles = lambda x: jnp.pad(x, ((0, bpad - b), (0, 0))).T.reshape(
+        x.shape[1], 8, bpad // 8)
+    moms_t = tiles(moms.astype(jnp.float32) / fact)
+    herm_t = tiles(herm.astype(jnp.float32) * sign)
+    y_t = tiles(y.astype(jnp.float32))
 
-    grid = (bpad // bp,)
+    lanes = bp // 8
+    spec = lambda c: pl.BlockSpec((c, 8, lanes), lambda i: (0, 0, i))
     out = pl.pallas_call(
         functools.partial(_kernel, p=p),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bp, k), lambda i: (i, 0)),
-            pl.BlockSpec((bp, k), lambda i: (i, 0)),
-            pl.BlockSpec((bp, 8), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bp,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((bpad,), jnp.float32),
-        compiler_params=kernels.tpu_compiler_params(
+        grid=(bpad // bp,),
+        in_specs=[spec(k), spec(k), spec(y.shape[1])],
+        out_specs=pl.BlockSpec((8, lanes), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((8, bpad // 8), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(pad2(moms), pad2(herm), y8)
-    return out[:b]
+    )(moms_t, herm_t, y_t)
+    return out.reshape(bpad)[:b]

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro import kernels
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BN = 2048
 
@@ -74,7 +74,7 @@ def msp_update(x, refrac, calcium, syn_input, uniform, *,
             jax.ShapeDtypeStruct((npad,), x.dtype),
             jax.ShapeDtypeStruct((npad,), calcium.dtype),
         ],
-        compiler_params=kernels.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)

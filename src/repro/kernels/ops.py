@@ -1,14 +1,14 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU backends the Pallas kernels run natively; anywhere
-else (this CPU container, tests) they run through the interpreter only when
-explicitly requested, otherwise the pure-jnp reference executes — interpret
-mode runs the kernel body in Python per grid step, which is correct but slow,
-so it is reserved for validation.
+Dispatch policy: on TPU the Pallas kernels run natively.  On CPU (tests,
+CI) they run through the interpreter only when explicitly requested,
+otherwise the pure-jnp reference executes — interpret mode runs the kernel
+body in Python per grid step, which is correct but slow, so it is reserved
+for validation.  Any other backend has neither path and is an error.
 
-    use_pallas=None   -> auto: Pallas on TPU, reference elsewhere
-    use_pallas=True   -> force Pallas (interpret=True off-TPU)
-    use_pallas=False  -> force reference
+    use_pallas=None   -> auto: Pallas on TPU, reference on CPU
+    use_pallas=True   -> force Pallas (interpret=True on CPU)
+    use_pallas=False  -> force reference, on every backend
 """
 from __future__ import annotations
 
@@ -34,17 +34,23 @@ def use_pallas_flag(backend: str) -> Optional[bool]:
     return {"reference": False, "pallas": True, "auto": None}[backend]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _platform() -> str:
+    return jax.default_backend()
 
 
 def _decide(use_pallas: Optional[bool]):
     """-> (run_pallas, interpret)"""
-    if use_pallas is None:
-        return (_on_tpu(), False)
-    if use_pallas:
-        return (True, not _on_tpu())
-    return (False, False)
+    if use_pallas is False:
+        return (False, False)
+    platform = _platform()
+    if platform == "tpu":
+        return (True, False)
+    if platform == "cpu":
+        return (bool(use_pallas), bool(use_pallas))
+    raise RuntimeError(
+        f"Pallas kernels run natively on TPU and interpreted on CPU; "
+        f"backend {platform!r} has neither (use_pallas=False selects the "
+        f"reference)")
 
 
 def gaussian_nbody(targets, sources, weights, delta,

@@ -117,8 +117,6 @@ def collective_census(hlo: str, body_trips: int = 1) -> Dict[str, Any]:
 
 
 def _first(d: Optional[Dict], *keys, default=0.0):
-    if isinstance(d, list):               # jax 0.4.x cost_analysis() -> [dict]
-        d = d[0] if d else None
     if not d:
         return default
     for k in keys:

@@ -53,7 +53,6 @@ from repro.core.traversal import FMMConfig
 from repro.serve import session as sess
 from repro.serve.batcher import SlotBatcher
 from repro.sharding import rules
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 
 class SlotExtras(NamedTuple):
@@ -219,12 +218,12 @@ class SimulationService:
             rec_template,
             self.axis,
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             round_body,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return jax.jit(sharded)
 

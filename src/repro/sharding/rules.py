@@ -14,23 +14,11 @@ the dry-run prints every fallback so sharding gaps are visible, not silent).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                        # jax 0.4.x home
-    from jax.experimental.shard_map import shard_map
-except ImportError:                         # moved to jax.shard_map in 0.5+
-    from jax import shard_map
-
-# The "skip the replication check" kwarg was renamed check_rep -> check_vma;
-# resolve it from the signature so callers stay version-agnostic.
-SHARD_MAP_NO_CHECK = {
-    ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-     else "check_rep"): False}
 
 PyTree = Any
 

@@ -1,7 +1,7 @@
 """Engine-level backend parity: Pallas kernels vs the pure-jnp reference.
 
 The contract (DESIGN.md §11): with `EngineConfig(backend="pallas")` — which
-off-TPU runs every kernel in interpret mode, numerically identical to the
+on CPU runs every kernel in interpret mode, numerically identical to the
 TPU lowering — a full `PlasticityEngine.simulate` reproduces
 `backend="reference"` for every search method.  The kernels were written to
 be BITWISE equal to the reference phase-1 update (same division, same
@@ -103,7 +103,7 @@ def test_hermite_tier_parity():
 
 
 def test_auto_backend_on_cpu_matches_reference():
-    """backend="auto" off-TPU must take the reference path exactly (the
+    """backend="auto" on CPU must take the reference path exactly (the
     zero-overhead default for CPU CI)."""
     fmm_cfg = FMMConfig(c1=8, c2=8)
     _, recs_ref = _run(EngineConfig(method="fmm", backend="reference"),

@@ -46,11 +46,10 @@ den = jnp.array(rng.integers(0, 3, n), jnp.float32)
 ref_levels = jax.jit(lambda a, d: octree.build_pyramid(
     seng.structure, seng.positions, a, d, fmm_cfg.delta))(ax, den)
 
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 from jax.sharding import PartitionSpec as P
-got_levels = jax.jit(shard_map(
+got_levels = jax.jit(jax.shard_map(
     lambda a, d: deng._local_pyramid(a, d), mesh=mesh,
-    in_specs=(P(), P()), out_specs=P(), **SHARD_MAP_NO_CHECK))(ax, den)
+    in_specs=(P(), P()), out_specs=P(), check_vma=False))(ax, den)
 # each box is aggregated wholly by its owner device, so the psum merge is
 # BITWISE equal to the single-device build
 for l, (a, b) in enumerate(zip(ref_levels, got_levels)):

@@ -258,7 +258,6 @@ def test_sharded_deletion_path_matches_plain_step():
     """Force the rare any-excess deletion branch (degrees > floor(elements))
     and check one full update step matches the plain engine bitwise."""
     from repro.core.distributed import DistributedPlasticityEngine
-    from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
     rng = np.random.default_rng(4)
     n = 64
     pos = rng.uniform(0, 1000.0, (n, 3)).astype(np.float32)
@@ -287,10 +286,10 @@ def test_sharded_deletion_path_matches_plain_step():
     ref_st, _ = jax.jit(lambda s, k: seng.step(
         s, k, do_update=jnp.bool_(True)))(state, key)
     state_spec, rec_spec = eng._specs()
-    dist_step = jax.jit(shard_map(
+    dist_step = jax.jit(jax.shard_map(
         lambda s, k: eng.local_step(s, k, do_update=jnp.bool_(True)),
         mesh=mesh, in_specs=(state_spec, P()),
-        out_specs=(state_spec, rec_spec), **SHARD_MAP_NO_CHECK))
+        out_specs=(state_spec, rec_spec), check_vma=False))
     got_st, _ = dist_step(state, key)
     for name in ("src", "dst", "valid"):
         np.testing.assert_array_equal(np.asarray(getattr(got_st.edges, name)),

@@ -1,9 +1,9 @@
-"""kernels/ops.py dispatch logic: _decide/_on_tpu and the backend mapping.
+"""kernels/ops.py dispatch logic: _decide/_platform and the backend mapping.
 
 The tri-state `use_pallas` flag and the EngineConfig `backend` strings are
 the only switchboard between the pure-jnp reference paths and the Pallas
 kernels (DESIGN.md §11); these tests pin the decision table down explicitly,
-including the off-TPU force-pallas -> interpret route.
+including the CPU force-pallas -> interpret route.
 """
 import numpy as np
 import pytest
@@ -19,23 +19,33 @@ from repro.kernels import ops, ref
     [
         # (run_pallas, interpret)
         (True, None, (True, False)),     # auto on TPU -> native Pallas
-        (False, None, (False, False)),   # auto off TPU -> reference
+        (False, None, (False, False)),   # auto on CPU -> reference
         (True, True, (True, False)),     # forced on TPU -> native Pallas
-        (False, True, (True, True)),     # forced off TPU -> interpret mode
+        (False, True, (True, True)),     # forced on CPU -> interpret mode
         (True, False, (False, False)),   # off -> reference, everywhere
         (False, False, (False, False)),
     ])
 def test_decide_table(monkeypatch, on_tpu, use_pallas, want):
-    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu" if on_tpu else "cpu")
     assert ops._decide(use_pallas) == want
+
+
+@pytest.mark.parametrize("use_pallas", [None, True])
+def test_decide_rejects_other_backends(monkeypatch, use_pallas):
+    """No silent fallback: a backend with neither a native lowering nor the
+    interpreter fails instead of quietly running the reference."""
+    monkeypatch.setattr(ops, "_platform", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._decide(use_pallas)
+    assert ops._decide(False) == (False, False)
 
 
 def test_on_tpu_matches_default_backend(monkeypatch):
     import jax
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ops._on_tpu()
+    assert ops._platform() == "tpu"
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    assert not ops._on_tpu()
+    assert ops._platform() == "cpu"
 
 
 # --- EngineConfig.backend -> use_pallas mapping ----------------------------

@@ -22,12 +22,6 @@ from repro.launch import steps as S
 from repro.models.config import ShapeConfig
 
 
-def _cost(compiled):
-    """jax 0.4.x returns [dict]; >= 0.5 returns dict."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, list) else ca
-
-
 def test_cost_analysis_counts_scan_body_once():
     def body(x, w):
         return jnp.tanh(x @ w), None
@@ -42,8 +36,8 @@ def test_cost_analysis_counts_scan_body_once():
 
     x = jnp.zeros((64, 128))
     ws = jnp.zeros((8, 128, 128))
-    f_scan = _cost(jax.jit(with_scan).lower(x, ws).compile())["flops"]
-    f_unr = _cost(jax.jit(unrolled).lower(x, ws).compile())["flops"]
+    f_scan = jax.jit(with_scan).lower(x, ws).compile().cost_analysis()["flops"]
+    f_unr = jax.jit(unrolled).lower(x, ws).compile().cost_analysis()["flops"]
     assert f_unr == pytest.approx(8 * f_scan, rel=0.05)
 
 
@@ -66,7 +60,7 @@ def test_analytic_forward_flops_match_hlo(arch, tol):
         inputs = jax.ShapeDtypeStruct((b, s), jnp.int32)
     compiled = jax.jit(lambda p, x: M.forward_train(p, x, cfg)) \
         .lower(params, inputs).compile()
-    hlo_flops = _cost(compiled)["flops"]
+    hlo_flops = compiled.cost_analysis()["flops"]
     est = FM.cell_cost(cfg, ShapeConfig("probe", s, b, "prefill"), 1)
     assert est.flops == pytest.approx(hlo_flops, rel=tol), \
         (est.flops, hlo_flops)
@@ -91,7 +85,7 @@ def test_param_count_analytic_vs_tree():
 
 def _hlo_flops(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
-    return _cost(compiled)["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 def test_kernel_costs_scale_linearly():

@@ -12,10 +12,7 @@ from repro.sharding import rules
 def _fake_mesh(shape=(16, 16), axes=("data", "model")):
     """An abstract mesh for spec construction only (no devices needed)."""
     from jax.sharding import AbstractMesh
-    try:                                   # jax >= 0.5: (shape, axis_names)
-        return AbstractMesh(shape, axes)
-    except TypeError:                      # jax 0.4.x: ((name, size), ...)
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 @pytest.mark.parametrize("arch", sorted(configs.ARCHS))

@@ -1,0 +1,80 @@
+"""The three Pallas kernels compile for a TPU v5e at engine widths.
+
+A chip is described, not attached: the TPU compiler refuses what the
+interpreter accepts (block layouts, unsupported shape casts, VMEM), so these
+compiles guard the native path without a chip.  Each kernel module is
+called with interpret=False and the compiled program must hold the Mosaic
+kernel (`tpu_custom_call`).
+
+The topology is described inside a module fixture only: only one process at
+a time may load the TPU library, and under several pytest workers a
+description made at import would make the other workers fail to collect.
+"""
+
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+
+from repro.core.msp import MSPConfig
+from repro.kernels import gaussian_nbody as gk
+from repro.kernels import m2l_pair
+from repro.kernels import msp_update as mk
+
+N = 131_072  # chip_smoke.py's engine width
+M2L_PAIRS = 262_144  # pairs at the deepest octree level of N neurons
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    # Keep the TPU library's logs out of the shared temp directory.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _kernel_cases():
+    f32, i32 = jnp.float32, jnp.int32
+    cfg = MSPConfig.calibrated(speedup=100.0)
+    kw = dict(
+        x0=cfg.x0,
+        tau_x=cfg.tau_x,
+        background=cfg.background,
+        w_syn=cfg.w_syn,
+        beta_ca=cfg.beta_ca,
+        tau_ca=cfg.tau_ca,
+        refractory=cfg.refractory,
+    )
+    return {
+        "gaussian_nbody": (
+            lambda t, s, w: gk.gaussian_nbody(t, s, w, 750.0**2, interpret=False),
+            [((4096, 3), f32), ((8192, 3), f32), ((8192,), f32)],
+        ),
+        "m2l_separable": (
+            lambda a, h, y: m2l_pair.m2l_separable(a, h, y, interpret=False),
+            [((M2L_PAIRS, 64), f32), ((M2L_PAIRS, 64), f32), ((M2L_PAIRS, 3), f32)],
+        ),
+        "msp_update": (
+            lambda *a: mk.msp_update(*a, interpret=False, **kw),
+            [((N,), f32), ((N,), i32), ((N,), f32), ((N,), f32), ((N,), f32)],
+        ),
+    }
+
+
+@pytest.mark.parametrize("name", ["gaussian_nbody", "m2l_separable", "msp_update"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_cases()[name]
+    compiled = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in compiled.as_text(), name

@@ -25,7 +25,6 @@ from repro.core.msp import MSPConfig
 from repro.core.traversal import FMMConfig
 from repro.core.distributed import (DistributedEnsembleEngine,
                                     DistributedPlasticityEngine)
-from repro.sharding.rules import SHARD_MAP_NO_CHECK, shard_map
 
 N = 96
 K = 2
@@ -54,9 +53,9 @@ def test_vmapped_update_keeps_deletion_gather_conditional():
 
     state_spec, _ = eng._specs()
     bspec = jax.tree.map(lambda s: P(None, *s), state_spec)
-    sharded = shard_map(batched_update, mesh=eng.mesh,
-                        in_specs=(bspec, P()), out_specs=bspec,
-                        **SHARD_MAP_NO_CHECK)
+    sharded = jax.shard_map(batched_update, mesh=eng.mesh,
+                            in_specs=(bspec, P()), out_specs=bspec,
+                            check_vma=False)
     jaxpr = jax.make_jaxpr(sharded)(states, keys)
 
     # Rule R3 asserts both directions at once: every edge-table-sized
