@@ -1,7 +1,8 @@
 """PlasticityEngine: the full MSP simulation loop (paper Sec. 3.1 + Sec. 4).
 
-Per activity step (phases 1 and 2): Poisson spiking + calcium + element
-growth.  Every `update_interval` steps (phase 3, the connectivity update):
+Per activity step (phases 1 and 2): synaptic input, then Poisson spiking +
+calcium + element growth, then the step's records.  Every
+`update_interval` steps (phase 3, the connectivity update):
 
     1. delete excess synapses (elements < synapses), both sides;
     2. recompute vacancies;
@@ -11,6 +12,12 @@ growth.  Every `update_interval` steps (phase 3, the connectivity update):
        (fmm | barnes_hut | direct);
     5. dendrite-side conflict resolution;
     6. commit accepted synapses.
+
+Each phase is a named scope, so every device op names it in a profiler
+trace (docs/tracing.md): `activity/synaptic_input`,
+`activity/neuron_update`, `activity/records`; then `update/delete`,
+`update/vacancies`, `update/upward`, `update/find` (with the FMM's
+`descent` and `leaf_resolve`), `update/conflicts`, `update/insert`.
 
 Everything is jit-compiled; the 500k-step outer loop is a `lax.scan` whose
 body applies the connectivity update under a `lax.cond` so one compilation
@@ -30,6 +37,7 @@ from repro.core import barnes_hut, msp, octree, synapses, traversal
 from repro.core.msp import MSPConfig, NeuronState
 from repro.core.synapses import SynapseState
 from repro.core.traversal import FMMConfig
+from repro.runtime import spans
 
 
 class SimState(NamedTuple):
@@ -138,6 +146,7 @@ class EngineConfig:
 class PlasticityEngine:
     """Owns the static structure; state flows through pure jitted functions."""
 
+    @spans.span("engine.init")
     def __init__(self, positions: np.ndarray,
                  msp_cfg: MSPConfig = MSPConfig(),
                  fmm_cfg: FMMConfig = FMMConfig(),
@@ -211,58 +220,68 @@ class PlasticityEngine:
     def connectivity_update(self, state: SimState, key: jax.Array,
                             params: Optional[KernelParams] = None,
                             n_active: Optional[jax.Array] = None) -> SimState:
+        # Each phase is a named scope (`update/<phase>`), so every device op
+        # of the update names its phase in the trace (docs/tracing.md).
         n = self.n
         rng = self.engine_cfg.rng
         fmm_cfg = self._runtime_fmm_cfg(params)
-        kdel, kfind, kconf = jax.random.split(key, 3)
         neurons, edges = state.neurons, state.edges
 
-        edges = synapses.delete_excess(edges, neurons.ax_elems,
-                                       neurons.den_elems, kdel, rng=rng)
-        out_deg = synapses.out_degree(edges, n)
-        in_deg = synapses.in_degree(edges, n)
-        ax_vac = jnp.maximum(
-            jnp.floor(neurons.ax_elems).astype(jnp.int32) - out_deg, 0
-        ).astype(jnp.float32)
-        den_vac = jnp.maximum(
-            jnp.floor(neurons.den_elems).astype(jnp.int32) - in_deg, 0
-        ).astype(jnp.float32)
+        with jax.named_scope("update/delete"):
+            kdel, kfind, kconf = jax.random.split(key, 3)
+            edges = synapses.delete_excess(edges, neurons.ax_elems,
+                                           neurons.den_elems, kdel, rng=rng)
+        with jax.named_scope("update/vacancies"):
+            out_deg = synapses.out_degree(edges, n)
+            in_deg = synapses.in_degree(edges, n)
+            ax_vac = jnp.maximum(
+                jnp.floor(neurons.ax_elems).astype(jnp.int32) - out_deg, 0
+            ).astype(jnp.float32)
+            den_vac = jnp.maximum(
+                jnp.floor(neurons.den_elems).astype(jnp.int32) - in_deg, 0
+            ).astype(jnp.float32)
 
         method = self.engine_cfg.method
         if method == "direct":
-            partner = barnes_hut.find_partners_direct(
-                self.positions, ax_vac, den_vac, kfind, fmm_cfg, rng=rng)
+            with jax.named_scope("update/find"):
+                partner = barnes_hut.find_partners_direct(
+                    self.positions, ax_vac, den_vac, kfind, fmm_cfg, rng=rng)
         else:
             build = octree.build_pyramid_m2m \
                 if self.engine_cfg.pyramid == "m2m" else octree.build_pyramid
-            levels = build(self.structure, self.positions,
-                           ax_vac, den_vac,
-                           fmm_cfg.delta, fmm_cfg.p)
-            if method == "fmm":
-                partner = traversal.find_partners(
-                    self.structure, levels, self.positions, ax_vac, den_vac,
-                    kfind, fmm_cfg, backend=self.engine_cfg.backend, rng=rng)
-            elif method == "barnes_hut":
-                partner = barnes_hut.find_partners_bh(
-                    self.structure, levels, self.positions, ax_vac, den_vac,
-                    kfind, fmm_cfg, rng=rng)
-            else:
-                raise ValueError(f"unknown method {method!r}")
+            with jax.named_scope("update/upward"):
+                levels = build(self.structure, self.positions,
+                               ax_vac, den_vac,
+                               fmm_cfg.delta, fmm_cfg.p)
+            with jax.named_scope("update/find"):
+                if method == "fmm":
+                    partner = traversal.find_partners(
+                        self.structure, levels, self.positions, ax_vac,
+                        den_vac, kfind, fmm_cfg,
+                        backend=self.engine_cfg.backend, rng=rng)
+                elif method == "barnes_hut":
+                    partner = barnes_hut.find_partners_bh(
+                        self.structure, levels, self.positions, ax_vac,
+                        den_vac, kfind, fmm_cfg, rng=rng)
+                else:
+                    raise ValueError(f"unknown method {method!r}")
 
-        req_cnt = jnp.minimum(ax_vac.astype(jnp.int32),
-                              self.engine_cfg.max_requests_per_neuron)
-        req_cnt = jnp.where(partner >= 0, req_cnt, 0)
-        accepted = synapses.resolve_conflicts(partner, req_cnt,
-                                              den_vac.astype(jnp.int32), kconf,
-                                              rng=rng)
-        # Padded subdomains restrict inserts to the active slot budget so
-        # slot placement matches the unpadded table (DESIGN.md §14).
-        cap = None if n_active is None else \
-            n_active * self.engine_cfg.edge_capacity_per_neuron
-        edges, dropped = synapses.insert(
-            edges, partner, accepted, self.engine_cfg.max_requests_per_neuron,
-            capacity=cap)
-        return state._replace(edges=edges, dropped=state.dropped + dropped)
+        with jax.named_scope("update/conflicts"):
+            req_cnt = jnp.minimum(ax_vac.astype(jnp.int32),
+                                  self.engine_cfg.max_requests_per_neuron)
+            req_cnt = jnp.where(partner >= 0, req_cnt, 0)
+            accepted = synapses.resolve_conflicts(
+                partner, req_cnt, den_vac.astype(jnp.int32), kconf, rng=rng)
+        with jax.named_scope("update/insert"):
+            # Padded subdomains restrict inserts to the active slot budget
+            # so slot placement matches the unpadded table (DESIGN.md §14).
+            cap = None if n_active is None else \
+                n_active * self.engine_cfg.edge_capacity_per_neuron
+            edges, dropped = synapses.insert(
+                edges, partner, accepted,
+                self.engine_cfg.max_requests_per_neuron, capacity=cap)
+            dropped = state.dropped + dropped
+        return state._replace(edges=edges, dropped=dropped)
 
     # -- one fused simulation step -----------------------------------------
     def step(self, state: SimState, key: jax.Array,
@@ -285,64 +304,71 @@ class PlasticityEngine:
                    (the batched streams are shape-dependent).  Records
                    reduce over the active rows only.
         """
-        kact, kconn = jax.random.split(key)
-        mask = None if n_active is None else \
-            jnp.arange(self.n, dtype=jnp.int32) < n_active
-        syn_in = synapses.synaptic_input(
-            state.edges, state.neurons.spiked,
-            self._runtime_sign(params, n_active))
-        neurons = msp.step_neurons(state.neurons, syn_in, kact, self.msp_cfg,
-                                   backend=self.engine_cfg.backend,
-                                   mask=mask, rng=self.engine_cfg.rng)
-        state = state._replace(neurons=neurons, step=state.step + 1)
-
-        if do_update is None:
-            do_update = (state.step % self.msp_cfg.update_interval) == 0
+        # Named scopes `activity/<phase>` name the step's device ops in the
+        # trace; the update branch names its own (`update/<phase>`), so the
+        # cond sits outside every activity scope (docs/tracing.md).
+        with jax.named_scope("activity/neuron_update"):
+            kact, kconn = jax.random.split(key)
+            mask = None if n_active is None else \
+                jnp.arange(self.n, dtype=jnp.int32) < n_active
+        with jax.named_scope("activity/synaptic_input"):
+            syn_in = synapses.synaptic_input(
+                state.edges, state.neurons.spiked,
+                self._runtime_sign(params, n_active))
+        with jax.named_scope("activity/neuron_update"):
+            neurons = msp.step_neurons(state.neurons, syn_in, kact,
+                                       self.msp_cfg,
+                                       backend=self.engine_cfg.backend,
+                                       mask=mask, rng=self.engine_cfg.rng)
+            state = state._replace(neurons=neurons, step=state.step + 1)
+            if do_update is None:
+                do_update = (state.step % self.msp_cfg.update_interval) == 0
         state = jax.lax.cond(
             do_update,
             lambda s: self.connectivity_update(s, kconn, params, n_active),
             lambda s: s,
             state)
-        # Order-deterministic reductions (synapses.det_sum): pad rows are
-        # exact zeros, and a sequential accumulation over [active | zeros] is
-        # bitwise the accumulation over the active prefix — `jnp.mean` would
-        # let XLA re-associate by LENGTH and break padded parity
-        # (DESIGN.md §14).  Integer sums are order-exact as-is.
-        cnt = jnp.asarray(self.n, jnp.float32) if n_active is None \
-            else n_active.astype(jnp.float32)
-        # Explicit reciprocal-multiply, NOT division: XLA strength-reduces
-        # division by a compile-time constant (the unpadded engine's n) but
-        # not by a traced scalar (the padded path's n_active), for a 1-ulp
-        # skew.  1/cnt is correctly rounded whether folded or computed, so
-        # sum * (1/cnt) is bitwise identical across the two paths.
-        inv = 1.0 / cnt
-        # `guard` is the active mask, or — unpadded — an all-true mask whose
-        # predicate depends on the traced step counter, so XLA cannot fold
-        # the select away.  The select between the square and det_sum's
-        # first add is what keeps the two programs bitwise aligned: without
-        # it LLVM contracts `d*d + partner` into an FMA in the unpadded
-        # fusion only (the padded one has the mask select in between),
-        # skewing calcium_std by 1 ulp (DESIGN.md §11, §14).
-        guard = mask if mask is not None else \
-            jnp.arange(self.n, dtype=jnp.int32) >= jnp.minimum(state.step, 0)
-        ca = jnp.where(guard, neurons.calcium, 0.0)
-        ca_mean = synapses.det_sum(ca) * inv
-        # Pin the mean's bits before the subtract: `calcium - det_sum*inv`
-        # is an fsub-of-fmul that LLVM contracts to an FMA in some fusion
-        # contexts (vmapped slots) but not others.  A guard select is NOT
-        # enough — LLVM distributes the sub over select(p, mul, 0) and
-        # contracts inside the arm — so the value is round-tripped through
-        # an integer add of a traced zero instead: no float rewrite can
-        # cross the int domain, and `+ min(step, 0)` (= 0, step never
-        # negative) cannot be folded because step is traced.
-        mean_g = _pin_f32(ca_mean, state.step)
-        dev2 = jnp.where(guard, (neurons.calcium - mean_g) ** 2, 0.0)
-        rec = StepRecord(
-            calcium_mean=ca_mean,
-            calcium_std=jnp.sqrt(synapses.det_sum(dev2) * inv),
-            num_synapses=jnp.sum(state.edges.valid.astype(jnp.int32)),
-            spike_rate=synapses.det_sum(
-                neurons.spiked.astype(jnp.float32)) * inv)
+        with jax.named_scope("activity/records"):
+            # Order-deterministic reductions (synapses.det_sum): pad rows are
+            # exact zeros, and a sequential accumulation over [active | zeros] is
+            # bitwise the accumulation over the active prefix — `jnp.mean` would
+            # let XLA re-associate by LENGTH and break padded parity
+            # (DESIGN.md §14).  Integer sums are order-exact as-is.
+            cnt = jnp.asarray(self.n, jnp.float32) if n_active is None \
+                else n_active.astype(jnp.float32)
+            # Explicit reciprocal-multiply, NOT division: XLA strength-reduces
+            # division by a compile-time constant (the unpadded engine's n) but
+            # not by a traced scalar (the padded path's n_active), for a 1-ulp
+            # skew.  1/cnt is correctly rounded whether folded or computed, so
+            # sum * (1/cnt) is bitwise identical across the two paths.
+            inv = 1.0 / cnt
+            # `guard` is the active mask, or — unpadded — an all-true mask whose
+            # predicate depends on the traced step counter, so XLA cannot fold
+            # the select away.  The select between the square and det_sum's
+            # first add is what keeps the two programs bitwise aligned: without
+            # it LLVM contracts `d*d + partner` into an FMA in the unpadded
+            # fusion only (the padded one has the mask select in between),
+            # skewing calcium_std by 1 ulp (DESIGN.md §11, §14).
+            guard = mask if mask is not None else \
+                jnp.arange(self.n, dtype=jnp.int32) >= jnp.minimum(state.step, 0)
+            ca = jnp.where(guard, neurons.calcium, 0.0)
+            ca_mean = synapses.det_sum(ca) * inv
+            # Pin the mean's bits before the subtract: `calcium - det_sum*inv`
+            # is an fsub-of-fmul that LLVM contracts to an FMA in some fusion
+            # contexts (vmapped slots) but not others.  A guard select is NOT
+            # enough — LLVM distributes the sub over select(p, mul, 0) and
+            # contracts inside the arm — so the value is round-tripped through
+            # an integer add of a traced zero instead: no float rewrite can
+            # cross the int domain, and `+ min(step, 0)` (= 0, step never
+            # negative) cannot be folded because step is traced.
+            mean_g = _pin_f32(ca_mean, state.step)
+            dev2 = jnp.where(guard, (neurons.calcium - mean_g) ** 2, 0.0)
+            rec = StepRecord(
+                calcium_mean=ca_mean,
+                calcium_std=jnp.sqrt(synapses.det_sum(dev2) * inv),
+                num_synapses=jnp.sum(state.edges.valid.astype(jnp.int32)),
+                spike_rate=synapses.det_sum(
+                    neurons.spiked.astype(jnp.float32)) * inv)
         return state, rec
 
     # -- whole-simulation scan ----------------------------------------------
@@ -371,10 +397,12 @@ class PlasticityEngine:
             # Fold by the CARRIED global step, not the local scan index:
             # identical for a fresh run (step == i), but a chunked/resumed
             # continuation draws fresh streams instead of replaying chunk 0's.
-            st, rec = self.step(st, jax.random.fold_in(key, st.step), params,
-                                n_active=n_active)
+            with jax.named_scope("activity/neuron_update"):
+                k = jax.random.fold_in(key, st.step)
+            st, rec = self.step(st, k, params, n_active=n_active)
             if probes is not None:
-                ps = probes.record(ps, prev, st, rec)
+                with jax.named_scope("activity/records"):
+                    ps = probes.record(ps, prev, st, rec)
             return (st, ps), rec
         (state, probe_state), recs = jax.lax.scan(
             body, (state, probe_state), jnp.arange(num_steps, dtype=jnp.int32))

@@ -381,11 +381,14 @@ def find_partners(structure: OctreeStructure, levels: List[LevelData],
                   cfg: FMMConfig, backend: str = "reference",
                   rng: str = "batched") -> jnp.ndarray:
     """Alg. 1 `find_synapses` (choice phase): per-neuron partner requests."""
-    k1, k2 = jax.random.split(key)
-    tgt_leaf = descend(structure, levels, k1, cfg, backend=backend, rng=rng)
-    my_tgt = tgt_leaf[jnp.asarray(structure.leaf_of)]
-    return resolve_leaf_partners(structure, positions, ax_vac, den_vac,
-                                 my_tgt, k2, cfg, rng=rng)
+    with jax.named_scope("descent"):
+        k1, k2 = jax.random.split(key)
+        tgt_leaf = descend(structure, levels, k1, cfg, backend=backend,
+                           rng=rng)
+        my_tgt = tgt_leaf[jnp.asarray(structure.leaf_of)]
+    with jax.named_scope("leaf_resolve"):
+        return resolve_leaf_partners(structure, positions, ax_vac, den_vac,
+                                     my_tgt, k2, cfg, rng=rng)
 
 
 def find_partners_sharded(structure: OctreeStructure, spans,
@@ -404,14 +407,17 @@ def find_partners_sharded(structure: OctreeStructure, spans,
     merge: the per-level descent-map reducer (see `descend_sharded`);
     level_data_fn: optional routed-exchange level supplier (DESIGN.md §13).
     """
-    k1, k2 = jax.random.split(key)
-    tgt_leaf = descend_sharded(structure, spans, rank, levels, k1, cfg, merge,
-                               backend=backend, level_data_fn=level_data_fn)
-    leaf_ids = jax.lax.dynamic_slice_in_dim(
-        jnp.asarray(structure.leaf_of, jnp.int32), row_start, row_count)
-    my_tgt = tgt_leaf[leaf_ids]
-    return resolve_leaf_partners(structure, positions, ax_vac, den_vac,
-                                 my_tgt, k2, cfg, row_start=row_start)
+    with jax.named_scope("descent"):
+        k1, k2 = jax.random.split(key)
+        tgt_leaf = descend_sharded(structure, spans, rank, levels, k1, cfg,
+                                   merge, backend=backend,
+                                   level_data_fn=level_data_fn)
+        leaf_ids = jax.lax.dynamic_slice_in_dim(
+            jnp.asarray(structure.leaf_of, jnp.int32), row_start, row_count)
+        my_tgt = tgt_leaf[leaf_ids]
+    with jax.named_scope("leaf_resolve"):
+        return resolve_leaf_partners(structure, positions, ax_vac, den_vac,
+                                     my_tgt, k2, cfg, row_start=row_start)
 
 
 # -- contract-auditor registry (repro.audit, DESIGN.md §15) -----------------

@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache at one fixed place per checkout.
 
 `enable()` is called by the entry points that compile full-size programs
-(`chip_smoke.py`, `benchmarks/run.py`) before their first compile:
+(`chip_smoke.py`, `benchmarks/run.py`, `benchmarks/chip/run.py`) before
+their first compile:
 
 * where `JAX_COMPILATION_CACHE_DIR` is set, JAX already keeps its cache
   there, and nothing else is set;

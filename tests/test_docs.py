@@ -151,3 +151,56 @@ def test_audit_guide_referenced_paths_exist():
     for p in sorted(set(paths)):
         assert os.path.isfile(os.path.join(ROOT, p)), \
             f"docs/audit.md references {p}, which does not exist"
+
+
+def test_tracing_guide_exists_and_is_linked():
+    path = os.path.join(ROOT, "docs", "tracing.md")
+    assert os.path.isfile(path), "docs/tracing.md missing"
+    with open(os.path.join(ROOT, "README.md")) as f:
+        assert "docs/tracing.md" in f.read(), \
+            "README.md no longer links the tracing guide"
+
+
+def test_tracing_guide_python_snippets_parse():
+    """Every ```python fence in docs/tracing.md must be valid syntax."""
+    with open(os.path.join(ROOT, "docs", "tracing.md")) as f:
+        text = f.read()
+    fences = re.findall(r"```python\n(.*?)```", text, re.S)
+    assert len(fences) >= 3, "the guide lost its worked examples"
+    for i, snippet in enumerate(fences):
+        try:
+            ast.parse(snippet)
+        except SyntaxError as e:
+            raise AssertionError(
+                f"docs/tracing.md python fence #{i} does not parse: {e}\n"
+                f"{snippet}") from None
+
+
+def test_tracing_guide_names_every_scope_and_span():
+    """The guide lists each scope the engine opens and each span the
+    recorder keeps, and its backticked repo paths exist."""
+    def source(module):
+        with open(os.path.join(ROOT, "src", "repro", "core", module)) as f:
+            return f.read()
+
+    with open(os.path.join(ROOT, "docs", "tracing.md")) as f:
+        text = f.read()
+    core = "".join(map(source, ("engine.py", "distributed.py", "traversal.py")))
+    names = set(re.findall(r'named_scope\(f?"([\w/{}]+)"\)', core))
+    dist = source("distributed.py")
+    names |= set(re.findall(r'_exchange\(\s*"(\w+)"', dist))
+    names |= set(re.findall(r'_gatherer\("(\w+)"', dist))
+    names.discard("exchange/{what}")
+    assert {"update/find", "descent", "spikes"} <= names
+    for name in sorted(names):
+        assert name in text, f"docs/tracing.md does not name {name}"
+    for name in ("engine.init", "compile.trace", "compile.lower",
+                 "compile.backend", "compile.cache_hit", "compile.cache_miss"):
+        assert f"`{name}`" in text
+    paths = re.findall(
+        r"`((?:src|tests|examples|benchmarks|docs|tools)/[\w./]+?"
+        r"\.(?:py|md))(?:::\w+)?`", text)
+    assert "src/repro/runtime/spans.py" in paths
+    for p in sorted(set(paths)):
+        assert os.path.isfile(os.path.join(ROOT, p)), \
+            f"docs/tracing.md references {p}, which does not exist"
