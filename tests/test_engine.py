@@ -2,6 +2,7 @@
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from repro.core.engine import EngineConfig, PlasticityEngine
 from repro.core.msp import MSPConfig
@@ -65,3 +66,36 @@ def test_determinism(short_runs):
                                   np.asarray(recs2.num_synapses))
     np.testing.assert_allclose(np.asarray(recs.calcium_mean),
                                np.asarray(recs2.calcium_mean), rtol=1e-6)
+
+
+def test_step_does_not_rest_on_the_live_prefix(short_runs, monkeypatch):
+    """Steps between two updates give bitwise the same records and neuron
+    state whether the live synapses sit in a prefix of the edge table or at
+    its end: the live prefix only bounds how much the input reads.  (The
+    runs stop before an update: deletion keys its priorities by slot.)"""
+    from repro.core import synapses
+    eng, st, _ = short_runs["fmm"]
+    monkeypatch.setattr(synapses, "LIVE_BLOCK", 1024)   # 25 blocks per table
+    e = st.edges.valid.shape[0]
+    live = np.flatnonzero(np.asarray(st.edges.valid))
+    assert 0 < live.size < e // 4
+
+    def laid_out(slots):
+        src = np.zeros(e, np.int32)
+        dst = np.zeros(e, np.int32)
+        valid = np.zeros(e, bool)
+        src[slots] = np.asarray(st.edges.src)[live]
+        dst[slots] = np.asarray(st.edges.dst)[live]
+        valid[slots] = True
+        return st._replace(edges=synapses.SynapseState(
+            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(valid)))
+
+    steps = eng.msp_cfg.update_interval // 2
+    assert int(st.step) % eng.msp_cfg.update_interval == 0
+    runs = [eng.simulate(laid_out(slots), jax.random.key(3), steps)
+            for slots in (np.arange(live.size), e - live.size + np.arange(live.size))]
+    (prefix, prefix_recs), (end, end_recs) = runs
+    for a, b in zip(jax.tree.leaves((prefix.neurons, prefix_recs)),
+                    jax.tree.leaves((end.neurons, end_recs))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert int(prefix_recs.num_synapses[-1]) == live.size

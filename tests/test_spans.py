@@ -180,6 +180,7 @@ def test_device_ops_name_their_layer_and_phase(method):
     assert unscoped == []
     assert seen["activity"] == ACTIVITY
     assert seen["update"] == (UPDATE - {"upward"} if method == "direct" else UPDATE)
+    assert "activity/synaptic_input/while/body/live_block/" in text
     if method == "fmm":
         for scope in ("update/find/descent/", "update/find/leaf_resolve/"):
             assert scope in text

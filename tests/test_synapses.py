@@ -1,5 +1,6 @@
 """Synapse store: deletion, conflict resolution, insertion (paper phase 3)."""
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -21,6 +22,74 @@ def test_degrees_and_input():
     syn_in = np.asarray(synapses.synaptic_input(st_, spiked))
     # edges from spiking 0 -> {1,2}; from spiking 2 -> {0}; invalid 3->0 ignored
     np.testing.assert_array_equal(syn_in, [1, 1, 1, 0])
+
+
+# Live slots of each layout: the block size is 256 slots, the table 1,024
+# (1,000 for "ragged_table", which is not a multiple of the block).
+_B = 256
+_LAYOUTS = {
+    "empty": lambda rng, e: np.zeros(e, bool),
+    "short_prefix": lambda rng, e: np.arange(e) < 100,
+    "one_block": lambda rng, e: np.arange(e) < _B,
+    "block_and_part": lambda rng, e: np.arange(e) < _B + 1,
+    "prefix_with_holes": lambda rng, e: (np.arange(e) < 700) & (rng.random(e) < 0.6),
+    "last_slot": lambda rng, e: np.arange(e) == e - 1,
+    "ragged_table": lambda rng, e: (np.arange(e) < 900) & (rng.random(e) < 0.8),
+}
+
+
+def _table(rng, n, e, live):
+    return synapses.SynapseState(
+        src=jnp.array(rng.integers(0, n, e), jnp.int32),
+        dst=jnp.array(rng.integers(0, n, e), jnp.int32),
+        valid=jnp.array(live))
+
+
+def _whole_table_input(state, spiked, sign):
+    contrib = (state.valid & spiked[state.src]).astype(jnp.float32)
+    if sign is not None:
+        contrib = contrib * sign[state.src]
+    return jax.ops.segment_sum(contrib, state.dst, num_segments=spiked.shape[-1])
+
+
+@pytest.mark.parametrize("signed", [False, True], ids=["unsigned", "signed"])
+@pytest.mark.parametrize("layout", [*_LAYOUTS, "vmap"])
+def test_live_prefix_input_equals_whole_table_sum(layout, signed, monkeypatch):
+    """The synaptic input read over the live prefix, block by block, is
+    bitwise the segment_sum over every slot of the table."""
+    monkeypatch.setattr(synapses, "LIVE_BLOCK", _B)
+
+    def read(*args):  # a function of its own, so no trace at another block size is reused
+        return synapses.synaptic_input(*args)
+
+    rng = np.random.default_rng(0)
+    n = 50
+    e = 1000 if layout == "ragged_table" else 1024
+    sign = jnp.array(np.where(rng.random(n) < 0.3, -1.0, 1.0), jnp.float32) if signed else None
+    if layout == "vmap":
+        # A different high-water mark per replica: the loop runs to the
+        # largest and the replicas below it read only empty slots there.
+        tables = [_table(rng, n, e, np.arange(e) < h) for h in (0, 90, _B + 90, 2 * _B + 1)]
+        state = jax.tree.map(lambda *a: jnp.stack(a), *tables)
+        spiked = jnp.array(rng.random((len(tables), n)) < 0.5)
+        got = jax.jit(jax.vmap(read, (0, 0, None)))(state, spiked, sign)
+        want = jax.vmap(_whole_table_input, (0, 0, None))(state, spiked, sign)
+        # The loop's counter stays one scalar: a batched bound would make
+        # it a predicated loop with a counter per replica.
+        jaxpr = jax.make_jaxpr(jax.vmap(synapses.synaptic_input, (0, 0, None)))(
+            state, spiked, sign)
+        (loop,) = [q for q in jaxpr.eqns if q.primitive.name == "while"]
+        assert loop.outvars[0].aval.shape == ()
+    else:
+        live = _LAYOUTS[layout](rng, e)
+        state = _table(rng, n, e, live)
+        # The source of the highest live slot spikes, so that slot counts.
+        top = np.flatnonzero(live).max(initial=0)
+        spiked = jnp.array(rng.random(n) < 0.5).at[state.src[top]].set(True)
+        got = jax.jit(read)(state, spiked, sign)
+        want = _whole_table_input(state, spiked, sign)
+    assert np.asarray(want).any() or layout == "empty"
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
 
 
 @settings(max_examples=25, deadline=None)
