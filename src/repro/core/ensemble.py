@@ -53,7 +53,10 @@ def scan_replicas(step_fn, states: SimState, keys: jax.Array,
     distributed.DistributedEnsembleEngine (replica axis x data axis).
 
     step_fn(state, key, params, do_update) -> (state, record) is vmapped over
-    the leading replica axis of (states, keys, params).  Two scheduling
+    the leading replica axis of (states, keys, params), inside the named
+    scope `replicas`: the step's ops read `replicas/vmap(activity/...)` and
+    the update's `replicas/vmap()/.../update/...` in a device trace
+    (docs/tracing.md).  Two scheduling
     details keep the batched program as cheap as sequential ones:
 
       * per-replica RNG keys fold by the CARRIED global step (see
@@ -113,12 +116,14 @@ def scan_replicas(step_fn, states: SimState, keys: jax.Array,
         if extras is not None:
             def one_served(s, k, p, e, q):
                 return step_fn(s, k, p, do_upd, e, q)
-            if params is None:
-                st, ps, rec = jax.vmap(
-                    lambda s, k, e, q: one_served(s, k, None, e, q))(
-                        st, ki, extras, ps)
-            else:
-                st, ps, rec = jax.vmap(one_served)(st, ki, params, extras, ps)
+            with jax.named_scope("replicas"):
+                if params is None:
+                    st, ps, rec = jax.vmap(
+                        lambda s, k, e, q: one_served(s, k, None, e, q))(
+                            st, ki, extras, ps)
+                else:
+                    st, ps, rec = jax.vmap(one_served)(st, ki, params,
+                                                       extras, ps)
             return (st, ps), rec
 
         def one(s, k, p, q):
@@ -128,11 +133,12 @@ def scan_replicas(step_fn, states: SimState, keys: jax.Array,
                 q = probes.record(q, prev, s, rec, merge=merge)
             return s, q, rec
 
-        if params is None:
-            st, ps, rec = jax.vmap(lambda s, k, q: one(s, k, None, q))(
-                st, ki, ps)
-        else:
-            st, ps, rec = jax.vmap(one)(st, ki, params, ps)
+        with jax.named_scope("replicas"):
+            if params is None:
+                st, ps, rec = jax.vmap(lambda s, k, q: one(s, k, None, q))(
+                    st, ki, ps)
+            else:
+                st, ps, rec = jax.vmap(one)(st, ki, params, ps)
         return (st, ps), rec
 
     (states, probe_states), recs = jax.lax.scan(

@@ -18,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import custom_batching
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,19 +54,41 @@ def msp_update(x, refrac, calcium, syn_input, uniform, *,
                x0, tau_x, background, w_syn, beta_ca, tau_ca, refractory,
                bn: int = DEFAULT_BN, interpret: bool = False):
     """Fused neuron update.  All inputs (n,); returns (x', refrac', spiked_f32,
-    calcium')."""
+    calcium').
+
+    Under `vmap` (K replicas, core/ensemble.py) the update stays one (K*n,)
+    call of the same kernel: it is per neuron and its constants are shared,
+    so the replica axis folds into the neuron axis.  Batching the
+    `pallas_call` itself would block each replica's row as
+    (Squeezed, bn), which Mosaic refuses (its last two block dimensions
+    must tile by 8 x 128)."""
+    kernel = functools.partial(
+        _kernel, x0=x0, tau_x=tau_x, background=background, w_syn=w_syn,
+        beta_ca=beta_ca, tau_ca=tau_ca, refractory=refractory)
+
+    @custom_batching.custom_vmap
+    def update(*args):
+        return _call(kernel, args, bn, interpret)
+
+    @update.def_vmap
+    def _replicas(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        outs = update(*(a.reshape(-1) for a in args))
+        return tuple(o.reshape(axis_size, -1) for o in outs), (True,) * 4
+
+    return update(x, refrac, calcium, syn_input, uniform)
+
+
+def _call(kernel, args, bn: int, interpret: bool):
+    x, refrac, calcium = args[:3]
     n = x.shape[0]
     npad = ((n + bn - 1) // bn) * bn
     pad = lambda a: jnp.pad(a, (0, npad - n))
-    args = (pad(x), pad(refrac), pad(calcium), pad(syn_input), pad(uniform))
-
-    grid = (npad // bn,)
     spec = pl.BlockSpec((bn,), lambda i: (i,))
     outs = pl.pallas_call(
-        functools.partial(_kernel, x0=x0, tau_x=tau_x, background=background,
-                          w_syn=w_syn, beta_ca=beta_ca, tau_ca=tau_ca,
-                          refractory=refractory),
-        grid=grid,
+        kernel,
+        grid=(npad // bn,),
         in_specs=[spec] * 5,
         out_specs=[spec] * 4,
         out_shape=[
@@ -77,5 +100,5 @@ def msp_update(x, refrac, calcium, syn_input, uniform, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(*args)
+    )(*(pad(a) for a in args))
     return tuple(o[:n] for o in outs)

@@ -199,3 +199,40 @@ print("OK")
                          capture_output=True, text=True, timeout=1200)
     assert res.returncode == 0, res.stderr[-2000:]
     assert "OK" in res.stdout
+
+
+def test_pallas_backend_matches_sequential_with_traced_knobs(engine):
+    """The sweep's chip path in miniature: backend="pallas" (the kernels,
+    interpreted on the CPU) under vmap, with sigma and the inhibitory
+    fraction traced per replica, over one update interval from a grown
+    state, equals K sequential runs of the same engine with each replica's
+    knobs — bitwise on the edge table, the neuron state and the records."""
+    # Built at the sweep's smallest sigma, whose guard admits expansions on
+    # both levels of this octree: the m2l_pair kernel runs in the descent.
+    fmm = FMMConfig(sigma=1000.0, c1=8, c2=8)
+    pallas = PlasticityEngine(engine.positions_np, engine.msp_cfg, fmm,
+                              EngineConfig(method="fmm", backend="pallas"))
+    grow = EnsembleEngine(PlasticityEngine(
+        engine.positions_np, engine.msp_cfg, fmm, EngineConfig(method="fmm")))
+    params = grow.default_params(K)._replace(
+        sigma=jnp.asarray([1000.0, 2000.0, 1000.0, 2000.0], jnp.float32),
+        inhibitory_fraction=jnp.asarray([0.0, 0.0, 0.25, 0.25], jnp.float32))
+    keys = jax.random.split(jax.random.key(11), K)
+    grown, _ = grow.simulate(grow.init_states(K), keys, 1100, params)
+    assert int(np.asarray(grown.edges.valid).sum(axis=1).min()) > 10
+    steps = pallas.msp_cfg.update_interval
+    ens = EnsembleEngine(pallas)
+    states, recs = ens.simulate(grown, keys, steps, params)
+    changed = 0
+    for r in range(K):
+        one = jax.tree.map(lambda a: a[r], grown)
+        p = jax.tree.map(lambda a: a[r], params)
+        st, rec = pallas.simulate(one, keys[r], steps, p)
+        for got, want in zip(jax.tree.leaves((states, recs)),
+                             jax.tree.leaves((st, rec))):
+            got = np.asarray(got)
+            got = got[r] if got.shape[:1] == (K,) else got[:, r]
+            np.testing.assert_array_equal(got, np.asarray(want))
+        changed += int(np.sum(np.asarray(st.edges.valid)
+                              != np.asarray(one.edges.valid)))
+    assert changed > 0  # the update formed or deleted synapses

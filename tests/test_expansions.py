@@ -78,6 +78,70 @@ def test_separable_m2l_equals_dense():
                                rtol=1e-3, atol=1e-3)
 
 
+def _series_f64(moms, herm, y, p=4):
+    """The translated series in float64, term by term: sum over alpha, beta
+    of (-1)^|alpha| A_alpha M_beta / beta! prod_d H_{alpha_d + beta_d}(y_d)."""
+    y = np.asarray(y, np.float64)
+    h = [np.ones_like(y), 2.0 * y]
+    for k in range(1, 2 * p - 2):
+        h.append(2.0 * y * h[k] - 2.0 * k * h[k - 1])
+    idx = mi.multi_indices(p)
+    sign, fact = mi.sign_table(p), mi.multi_factorial(p)
+    out = np.zeros(y.shape[:-1])
+    for i, alpha in enumerate(idx):
+        for j, beta in enumerate(idx):
+            term = np.prod([h[alpha[d] + beta[d]][..., d] for d in range(3)], axis=0)
+            out += sign[i] * np.asarray(herm, np.float64)[..., i] \
+                * np.asarray(moms, np.float64)[..., j] / fact[j] * term
+    return out
+
+
+def _sweep_pairs(k=2, b=64):
+    """Taylor-tier inputs of replicas with different sigma at the deepest
+    level's scale (box side 62.5 um, sigma 300 and 750): box sums of 4
+    points a box, offsets from the box centre over sigma."""
+    rng = np.random.default_rng(16)
+    sigma = np.array([300.0, 750.0], np.float32)[:k]
+    s_c = rng.uniform(0, 1000, (k, b, 1, 3))
+    d_c = s_c + rng.uniform(-150, 150, (k, b, 8, 3))
+    def sums(scale, weights_of):
+        x = rng.uniform(-31.25, 31.25, (k, b, scale, 4, 3)) / sigma[:, None, None, None, None]
+        w = weights_of((k, b, scale, 4))
+        return np.sum(w[..., None] * np.asarray(mi.monomials(jnp.asarray(x))), axis=-2)
+    moms = sums(1, lambda sh: rng.integers(0, 3, sh).astype(float))
+    herm = sums(8, lambda sh: rng.integers(5, 30, sh).astype(float)) / mi.multi_factorial()
+    return moms, s_c, herm, d_c, sigma
+
+
+def test_taylor_series_matches_float64_under_vmap():
+    """The reference Taylor tier under vmap over replicas with different
+    sigma gives the log masses of the float64 series, to float32 rounding
+    (on a TPU the einsum form of the series read -69 where float64 reads
+    +4 to +6, on about a fifth of the deepest level's pairs)."""
+    import jax
+    moms, s_c, herm, d_c, sigma = _sweep_pairs()
+    args = [jnp.asarray(a, jnp.float32) for a in (moms, s_c, herm, d_c, sigma ** 2)]
+    got = np.asarray(jax.jit(jax.vmap(ex.box_mass_taylor_log))(*args), np.float64)
+    y = (d_c - s_c) / sigma[:, None, None, None]
+    want = -np.sum(y * y, axis=-1) + np.log(_series_f64(moms, herm, y))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)
+
+
+def test_taylor_series_elementwise_under_vmap():
+    """The reference Taylor tier contracts its Hankel matrices with
+    elementwise multiply-adds, so its vmapped jaxpr holds no dot_general.
+    On a TPU v5e under vmap, the same contractions as `jnp.einsum`
+    dot_generals gave wrong series at every matmul precision, with the
+    Hankel rows gathered or sliced, while the gathered rows contracted
+    elementwise agreed with float64: the fault shows only on the chip, so
+    the op is pinned here."""
+    import jax
+    moms, s_c, herm, d_c, sigma = _sweep_pairs()
+    args = [jnp.asarray(a, jnp.float32) for a in (moms, s_c, herm, d_c, sigma ** 2)]
+    jaxpr = str(jax.make_jaxpr(jax.vmap(ex.box_mass_taylor_log))(*args))
+    assert "dot_general" not in jaxpr
+
+
 def test_log_masses_match_linear_paths():
     src, tgt, w, a, s_c, t_c = _boxes(11)
     herm = ex.hermite_coefficients(src, w, s_c, DELTA)

@@ -83,6 +83,30 @@ def test_msp_update_kernel_bitwise():
         np.testing.assert_array_equal(np.asarray(ai), np.asarray(bi))
 
 
+def test_msp_update_kernel_batched_bitwise():
+    """Under vmap the kernel folds the replica axis into the neuron axis
+    (one call over K*n neurons, as the sweep runs it on a TPU): each
+    replica's rows equal the reference update of that replica alone,
+    bitwise, for an n that is not a multiple of the block."""
+    import jax
+    k, n = 3, 1000
+    assert n % mk.DEFAULT_BN
+    rng = np.random.default_rng(12)
+    x = jnp.array(rng.uniform(0, 0.2, (k, n)), jnp.float32)
+    refrac = jnp.array(rng.integers(0, 5, (k, n)), jnp.int32)
+    ca = jnp.array(rng.uniform(0, 1, (k, n)), jnp.float32)
+    syn = jnp.array(rng.integers(0, 4, (k, n)), jnp.float32)
+    u = jnp.array(rng.uniform(0, 1, (k, n)), jnp.float32)
+    cfg = MSPConfig.calibrated(speedup=100.0)
+    batched = jax.jit(jax.vmap(
+        lambda *a: ops.msp_update(*a, cfg, use_pallas=True)))(x, refrac, ca, syn, u)
+    one = jax.jit(lambda *a: ops.msp_update(*a, cfg, use_pallas=False))
+    for r in range(k):
+        want = one(x[r], refrac[r], ca[r], syn[r], u[r])
+        for got, w in zip(batched, want):
+            np.testing.assert_array_equal(np.asarray(got[r]), np.asarray(w))
+
+
 @pytest.mark.parametrize("b", [1, 63, 512, 700])
 def test_m2l_kernel_shapes(b):
     rng = np.random.default_rng(b)

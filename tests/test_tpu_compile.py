@@ -24,6 +24,9 @@ from repro.kernels import msp_update as mk
 
 N = 131_072  # chip_smoke.py's engine width
 M2L_PAIRS = 262_144  # pairs at the deepest octree level of N neurons
+# The sweep's cell: K replicas of N_REPLICA neurons under vmap, and the
+# Taylor-tier pairs of one replica's deepest level with expansions.
+K, N_REPLICA, M2L_PAIRS_REPLICA = 8, 16_384, 32_768
 
 
 @pytest.fixture(scope="module")
@@ -73,8 +76,38 @@ def _kernel_cases():
     }
 
 
+def _batched_cases():
+    """The kernels under vmap over the replica axis, as the sweep runs them."""
+    f32, i32 = jnp.float32, jnp.int32
+    kernel = _kernel_cases()
+    msp, _ = kernel["msp_update"]
+    m2l, _ = kernel["m2l_separable"]
+    per = (K, N_REPLICA)
+    pairs = (K, M2L_PAIRS_REPLICA)
+    return {
+        "msp_update": (
+            jax.vmap(msp),
+            [(per, f32), (per, i32), (per, f32), (per, f32), (per, f32)],
+        ),
+        "m2l_separable": (
+            jax.vmap(m2l),
+            [(pairs + (64,), f32), (pairs + (64,), f32), (pairs + (3,), f32)],
+        ),
+    }
+
+
 @pytest.mark.parametrize("name", ["gaussian_nbody", "m2l_separable", "msp_update"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _kernel_cases()[name]
+    compiled = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+@pytest.mark.parametrize("name", ["m2l_separable", "msp_update"])
+def test_batched_kernel_compiles_for_v5e(one_chip, name):
+    """Under vmap over K = 8 replicas the kernels still compile for the chip
+    (a batched `pallas_call` of a 1-D block is refused by Mosaic), and the
+    program holds the Mosaic kernel."""
+    fn, shapes = _batched_cases()[name]
     compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text(), name
