@@ -13,12 +13,11 @@ from typing import Dict
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core.synapses import SynapseState, in_degree, out_degree
+from repro.core.synapses import SynapseState, degrees
 
 
 def degree_statistics(edges: SynapseState, n: int) -> Dict[str, jnp.ndarray]:
-    out_d = out_degree(edges, n)
-    in_d = in_degree(edges, n)
+    out_d, in_d = degrees(edges, n)
     return {
         "out_mean": jnp.mean(out_d.astype(jnp.float32)),
         "out_std": jnp.std(out_d.astype(jnp.float32)),

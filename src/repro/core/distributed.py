@@ -379,8 +379,7 @@ class DistributedPlasticityEngine(PlasticityEngine):
             den_el_g = gather(state.neurons.den_elems)
             edges_g = synapses.delete_excess(edges_g, ax_el_g, den_el_g, kdel)
         with jax.named_scope("update/vacancies"):
-            out_deg = synapses.out_degree(edges_g, n)
-            in_deg = synapses.in_degree(edges_g, n)
+            out_deg, in_deg = synapses.degrees(edges_g, n)
             ax_vac = jnp.maximum(jnp.floor(ax_el_g).astype(jnp.int32)
                                  - out_deg, 0).astype(jnp.float32)
             den_vac = jnp.maximum(jnp.floor(den_el_g).astype(jnp.int32)

@@ -18,6 +18,8 @@ trace (docs/tracing.md): `activity/synaptic_input`,
 `activity/neuron_update`, `activity/records`; then `update/delete`,
 `update/vacancies`, `update/upward`, `update/find` (with the FMM's
 `descent` and `leaf_resolve`), `update/conflicts`, `update/insert`.
+Deletion, the vacancies' degrees and insert pass over the live extent of
+the edge table only, inside a scope `live_extent_<slots>` that names it.
 
 Everything is jit-compiled; the 500k-step outer loop is a `lax.scan` whose
 body applies the connectivity update under a `lax.cond` so one compilation
@@ -232,8 +234,7 @@ class PlasticityEngine:
             edges = synapses.delete_excess(edges, neurons.ax_elems,
                                            neurons.den_elems, kdel, rng=rng)
         with jax.named_scope("update/vacancies"):
-            out_deg = synapses.out_degree(edges, n)
-            in_deg = synapses.in_degree(edges, n)
+            out_deg, in_deg = synapses.degrees(edges, n)
             ax_vac = jnp.maximum(
                 jnp.floor(neurons.ax_elems).astype(jnp.int32) - out_deg, 0
             ).astype(jnp.float32)

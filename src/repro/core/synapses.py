@@ -95,34 +95,83 @@ def empty(capacity: int) -> SynapseState:
                         valid=jnp.zeros((capacity,), bool))
 
 
-def out_degree(state: SynapseState, n: int) -> jnp.ndarray:
-    return _segment_sum_n(state.valid.astype(jnp.int32), state.src, n)
-
-
-def in_degree(state: SynapseState, n: int) -> jnp.ndarray:
-    return _segment_sum_n(state.valid.astype(jnp.int32), state.dst, n)
-
-
 # Slots per block of the synaptic input's loop over the live prefix.  On a
 # v5e a slot costs ~15 ns and a block ~4 us more, so smaller blocks waste
 # fewer empty slots past the live prefix; 2**14 keeps a full table within
 # 2 % of one pass over it (PERF.md, Findings).
 LIVE_BLOCK = 1 << 14
 
+# Extents of the connectivity update's passes over the table, below the
+# table's own size, which tops the ladder: each pass runs over the smallest
+# that holds what it must see (`_at_live_extent`), so it does at most 4x the
+# work needed, and a table of 2**23 slots compiles six of each pass.
+LIVE_EXTENTS = tuple(LIVE_BLOCK << 2 * j for j in range(5))
+
 
 @custom_batching.custom_vmap
-def _live_bound(valid: jnp.ndarray) -> jnp.ndarray:
+def _largest(x: jnp.ndarray) -> jnp.ndarray:
+    """x; under vmap the largest over the replicas, unbatched."""
+    return x
+
+
+@_largest.def_vmap
+def _largest_batched(axis_size, in_batched, x):
+    # Unbatched, so a loop bound or a switch index computed from it stays
+    # one scalar: a plain while loop or switch, where a batched one would
+    # be predicated per replica or select over every branch.
+    return _largest(jnp.max(x, axis=0) if in_batched[0] else x), False
+
+
+def _high_water(valid: jnp.ndarray) -> jnp.ndarray:
     """One past the highest valid slot (0 for an empty table)."""
     slot = jnp.arange(1, valid.shape[-1] + 1, dtype=jnp.int32)
     return jnp.max(jnp.where(valid, slot, 0))
 
 
-@_live_bound.def_vmap
-def _live_bound_batched(axis_size, in_batched, valid):
-    # The max over every replica, unbatched: the block loop's bound stays
-    # one scalar, so under vmap it stays a plain while loop instead of a
-    # predicated one that selects per replica.
-    return _live_bound(valid), False
+def _live_bound(valid: jnp.ndarray) -> jnp.ndarray:
+    """The high-water mark; under vmap the largest over the replicas."""
+    return _largest(_high_water(valid))
+
+
+def _at_live_extent(e: int, need: jnp.ndarray, fn):
+    """fn(x) for the smallest extent x of the ladder of an e-slot table
+    (LIVE_EXTENTS below e, then e) with x >= need.
+
+    `need` is a scalar read from the table (`_live_bound` or `_largest`), so
+    under vmap the switch stays a switch: every replica runs the largest
+    replica's extent.  Each branch runs in scope `live_extent_<x>`."""
+    extents = (*(x for x in LIVE_EXTENTS if x < e), e)
+
+    def branch(x):
+        def run():
+            with jax.named_scope(f"live_extent_{x}"):
+                return fn(x)
+        return run
+
+    index = sum((need > x).astype(jnp.int32) for x in extents[:-1])
+    return jax.lax.switch(index, [branch(x) for x in extents])
+
+
+def _put_prefix(full: jnp.ndarray, prefix: jnp.ndarray) -> jnp.ndarray:
+    """`full` with its leading slots replaced by `prefix`."""
+    if prefix.shape == full.shape:
+        return prefix
+    return jax.lax.dynamic_update_slice_in_dim(full, prefix, 0, full.ndim - 1)
+
+
+def _degrees_of(src, dst, valid, n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(out-degree, in-degree): the valid slots summed by src and by dst."""
+    v = valid.astype(jnp.int32)
+    return _segment_sum_n(v, src, n), _segment_sum_n(v, dst, n)
+
+
+def degrees(state: SynapseState, n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(out-degree, in-degree) of every neuron, summed over the live extent
+    of the table (every slot at or above the high-water mark is invalid,
+    and integer sums are exact)."""
+    def at(x):
+        return _degrees_of(state.src[:x], state.dst[:x], state.valid[:x], n)
+    return _at_live_extent(state.valid.shape[0], _live_bound(state.valid), at)
 
 
 def synaptic_input(state: SynapseState, spiked: jnp.ndarray,
@@ -210,7 +259,11 @@ def delete_excess(state: SynapseState, ax_elems: jnp.ndarray,
     rng="counter" keys each edge slot's priority by its SLOT INDEX
     (streams.bits_at) instead of one shape-(E,) draw, so a table padded
     with extra (never-valid) slots ranks its shared prefix identically to
-    the unpadded table (DESIGN.md §14)."""
+    the unpadded table (DESIGN.md §14).
+
+    Degrees and rankings run over the live extent of the table only
+    (`_at_live_extent`): the smallest rung of LIVE_EXTENTS, or the whole
+    table, that holds its high-water mark."""
     fn = _DELETE_EXCESS_VALID[rng]
     new_valid = fn(state.src, state.dst, state.valid,
                    ax_elems, den_elems, key)
@@ -218,34 +271,42 @@ def delete_excess(state: SynapseState, ax_elems: jnp.ndarray,
 
 
 def _make_delete_excess_valid(counter: bool):
-    def prio_bits(k, e):
+    def prio_bits(k, e, x):
+        # The first x of the whole table's e priorities.
         if counter:
-            return streams.bits_at(k, jnp.arange(e, dtype=jnp.int32))
-        return jax.random.bits(k, (e,), jnp.uint32)
+            return streams.bits_at(k, jnp.arange(x, dtype=jnp.int32))
+        return jax.random.bits(k, (e,), jnp.uint32)[:x]
+
+    # Both rules pass over the live extent of the table only: every slot at
+    # or above the high-water mark is invalid, so it adds nothing to a
+    # degree, and it ranks after every valid slot (seg_key INT32_MAX), so
+    # every valid slot's rank, and so every kill, is the whole table's.
 
     @custom_batching.custom_vmap
     def _valid_fn(src, dst, valid, ax_elems, den_elems, key):
         n = ax_elems.shape[0]
         e = src.shape[0]
         k1, k2 = jax.random.split(key)
-        out_deg = jax.ops.segment_sum(valid.astype(jnp.int32), src,
-                                      num_segments=n)
-        in_deg = jax.ops.segment_sum(valid.astype(jnp.int32), dst,
-                                     num_segments=n)
-        excess_out = jnp.maximum(
-            out_deg - jnp.floor(ax_elems).astype(jnp.int32), 0)
-        excess_in = jnp.maximum(
-            in_deg - jnp.floor(den_elems).astype(jnp.int32), 0)
 
-        def side(seg_ids, excess, k):
-            def live(_):
-                rank = _rank_within_segment(seg_ids, prio_bits(k, e), valid)
-                return rank < excess[seg_ids]
-            return jax.lax.cond(jnp.any(excess > 0), live,
-                                lambda _: jnp.zeros(seg_ids.shape, bool), None)
+        def at(x):
+            s, d, v = src[:x], dst[:x], valid[:x]
+            out_deg, in_deg = _degrees_of(s, d, v, n)
+            excess_out = jnp.maximum(
+                out_deg - jnp.floor(ax_elems).astype(jnp.int32), 0)
+            excess_in = jnp.maximum(
+                in_deg - jnp.floor(den_elems).astype(jnp.int32), 0)
 
-        kill = side(src, excess_out, k1) | side(dst, excess_in, k2)
-        return valid & ~kill
+            def side(seg_ids, excess, k):
+                def live(_):
+                    rank = _rank_within_segment(seg_ids, prio_bits(k, e, x), v)
+                    return rank < excess[seg_ids]
+                return jax.lax.cond(jnp.any(excess > 0), live,
+                                    lambda _: jnp.zeros((x,), bool), None)
+
+            kill = side(s, excess_out, k1) | side(d, excess_in, k2)
+            return _put_prefix(valid, v & ~kill)
+
+        return _at_live_extent(e, _live_bound(valid), at)
 
     @_valid_fn.def_vmap
     def _valid_fn_batched(axis_size, in_batched,
@@ -260,30 +321,37 @@ def _make_delete_excess_valid(counter: bool):
         e = src.shape[-1]
         offs = (jnp.arange(kk, dtype=src.dtype) * n)[:, None]      # (K,1)
         flat = lambda ids: (ids + offs).reshape(-1)
-        deg = lambda ids: jax.ops.segment_sum(
-            valid.astype(jnp.int32).reshape(-1), flat(ids),
-            num_segments=kk * n).reshape(kk, n)
-        excess_out = jnp.maximum(
-            deg(src) - jnp.floor(ax_elems).astype(jnp.int32), 0)
-        excess_in = jnp.maximum(
-            deg(dst) - jnp.floor(den_elems).astype(jnp.int32), 0)
         ks = jax.vmap(jax.random.split)(key)                       # (K,2)
 
-        def side(seg_ids, excess, k):
-            def live(_):
-                prio = jax.vmap(lambda kr: prio_bits(kr, e))(k)
-                # Disjoint replica-offset segments: per-edge ranks are
-                # identical to the per-replica ranking (stable sort,
-                # per-replica prio bits).
-                rank = _rank_within_segment(flat(seg_ids), prio.reshape(-1),
-                                            valid.reshape(-1))
-                return (rank
-                        < excess.reshape(-1)[flat(seg_ids)]).reshape(kk, e)
-            return jax.lax.cond(jnp.any(excess > 0), live,
-                                lambda _: jnp.zeros((kk, e), bool), None)
+        def at(x):
+            # One extent for every replica: the largest replica's.
+            s, d, v = src[:, :x], dst[:, :x], valid[:, :x]
+            deg = lambda ids: jax.ops.segment_sum(
+                v.astype(jnp.int32).reshape(-1), flat(ids),
+                num_segments=kk * n).reshape(kk, n)
+            excess_out = jnp.maximum(
+                deg(s) - jnp.floor(ax_elems).astype(jnp.int32), 0)
+            excess_in = jnp.maximum(
+                deg(d) - jnp.floor(den_elems).astype(jnp.int32), 0)
 
-        kill = side(src, excess_out, ks[:, 0]) | side(dst, excess_in, ks[:, 1])
-        return valid & ~kill, True
+            def side(seg_ids, excess, k):
+                def live(_):
+                    prio = jax.vmap(lambda kr: prio_bits(kr, e, x))(k)
+                    # Disjoint replica-offset segments: per-edge ranks are
+                    # identical to the per-replica ranking (stable sort,
+                    # per-replica prio bits).
+                    rank = _rank_within_segment(flat(seg_ids),
+                                                prio.reshape(-1),
+                                                v.reshape(-1))
+                    return (rank
+                            < excess.reshape(-1)[flat(seg_ids)]).reshape(kk, x)
+                return jax.lax.cond(jnp.any(excess > 0), live,
+                                    lambda _: jnp.zeros((kk, x), bool), None)
+
+            kill = side(s, excess_out, ks[:, 0]) | side(d, excess_in, ks[:, 1])
+            return _put_prefix(valid, v & ~kill)
+
+        return _at_live_extent(e, _live_bound(valid), at), True
 
     return _valid_fn
 
@@ -463,23 +531,33 @@ def insert(state: SynapseState, partner: jnp.ndarray, accepted: jnp.ndarray,
     Returns (new_state, number_of_dropped_units) — units are dropped only if
     the edge capacity overflows (sized generously by the engine; the counter
     feeds the fault-tolerance telemetry rather than silently truncating).
+
+    Passes over the live extent of the table only: the high-water mark plus
+    the units to commit (the whole table when they overflow it).
     """
     n = partner.shape[0]
     k = max_per_neuron
     buf_src, buf_dst, total_new = _stage_units(partner, accepted, k)
 
-    free = ~state.valid
-    if capacity is not None:
-        free = free & (jnp.arange(free.shape[0], dtype=jnp.int32) < capacity)
-    free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1            # (E,)
-    take = free & (free_rank < total_new) & (free_rank < n * k)
-    pick = jnp.minimum(free_rank, n * k - 1)
-    new_src = jnp.where(take, buf_src[pick], state.src)
-    new_dst = jnp.where(take, buf_dst[pick], state.dst)
-    new_valid = state.valid | take
-    placed = jnp.sum(take.astype(jnp.int32))
-    dropped = total_new - placed
-    return SynapseState(src=new_src, dst=new_dst, valid=new_valid), dropped
+    def at(x):
+        free = ~state.valid[:x]
+        if capacity is not None:
+            free = free & (jnp.arange(x, dtype=jnp.int32) < capacity)
+        free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1        # (x,)
+        take = free & (free_rank < total_new) & (free_rank < n * k)
+        pick = jnp.minimum(free_rank, n * k - 1)
+        new = SynapseState(src=jnp.where(take, buf_src[pick], state.src[:x]),
+                           dst=jnp.where(take, buf_dst[pick], state.dst[:x]),
+                           valid=state.valid[:x] | take)
+        placed = jnp.sum(take.astype(jnp.int32))
+        return jax.tree.map(_put_prefix, state, new), total_new - placed
+
+    # The slots from the high-water mark up to the extent are free, and
+    # there are at least total_new of them, so no unit lands at or above
+    # the extent, unless it is the whole table.  (Under `capacity` no slot
+    # at or above it is free anyway.)
+    need = _largest(_high_water(state.valid) + total_new)
+    return _at_live_extent(state.valid.shape[0], need, at)
 
 
 def insert_span(state: SynapseState, partner: jnp.ndarray,

@@ -4,8 +4,8 @@ The 500 000-step outer loop applies, per neuron: membrane decay + input,
 spike draw, refractory bookkeeping, and the calcium trace.  Unfused, that is
 6+ HBM round-trips of (n,)-arrays per step; fused it is one read + one write
 per array — the step becomes bandwidth-minimal.  (XLA usually fuses these
-too; the kernel makes the schedule explicit, keeps the whole working set in
-VMEM, and is the anchor point for the multi-step in-VMEM variant noted in
+too; the kernel makes the schedule explicit, streams each array through
+VMEM in blocks, and is the anchor point for the multi-step in-VMEM variant noted in
 EXPERIMENTS.md §Perf.)
 
 All model constants are baked in as compile-time scalars (they never change
@@ -22,7 +22,10 @@ from jax import custom_batching
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BN = 2048
+# Neurons per block.  The arrays stay in HBM (`_call`), so the kernel's time
+# is its DMAs': on a v5e, 131,072 neurons take ~6.8 us a step in blocks of
+# 65,536 (256 KiB a DMA) and ~22 us in blocks of 2,048 (8 KiB a DMA).
+DEFAULT_BN = 65536
 
 
 def _kernel(x_ref, refrac_ref, ca_ref, syn_ref, u_ref,
@@ -83,22 +86,31 @@ def msp_update(x, refrac, calcium, syn_input, uniform, *,
 def _call(kernel, args, bn: int, interpret: bool):
     x, refrac, calcium = args[:3]
     n = x.shape[0]
-    npad = ((n + bn - 1) // bn) * bn
+    # As many blocks as bn asks for, each whole 1024-lane tiles: a block
+    # pads less than one tile.
+    grid = -(-n // bn)
+    bn = -(-n // (grid * 1024)) * 1024
+    npad = grid * bn
     pad = lambda a: jnp.pad(a, (0, npad - n))
+    ins = [pad(a) for a in args]
+    out_shape = [jax.ShapeDtypeStruct((npad,), dt) for dt in
+                 (x.dtype, refrac.dtype, x.dtype, calcium.dtype)]
+    if not interpret:
+        # Operands and results are held in HBM, and the kernel's own block
+        # pipeline streams them through VMEM: left free, XLA's memory-space
+        # assignment may stage them in VMEM with copies outside the kernel,
+        # which then hide the kernel's traffic from its device time.
+        ins = [pltpu.with_memory_space_constraint(a, pltpu.HBM) for a in ins]
+        out_shape = [pltpu.HBM(o.shape, o.dtype) for o in out_shape]
     spec = pl.BlockSpec((bn,), lambda i: (i,))
     outs = pl.pallas_call(
         kernel,
-        grid=(npad // bn,),
+        grid=(grid,),
         in_specs=[spec] * 5,
         out_specs=[spec] * 4,
-        out_shape=[
-            jax.ShapeDtypeStruct((npad,), x.dtype),
-            jax.ShapeDtypeStruct((npad,), refrac.dtype),
-            jax.ShapeDtypeStruct((npad,), x.dtype),
-            jax.ShapeDtypeStruct((npad,), calcium.dtype),
-        ],
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(*(pad(a) for a in args))
+    )(*ins)
     return tuple(o[:n] for o in outs)

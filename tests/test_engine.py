@@ -50,8 +50,7 @@ def test_edge_list_consistent_with_elements(short_runs):
     synaptic elements (the deletion invariant)."""
     for method, (eng, st, recs) in short_runs.items():
         from repro.core import synapses
-        out_deg = np.asarray(synapses.out_degree(st.edges, eng.n))
-        in_deg = np.asarray(synapses.in_degree(st.edges, eng.n))
+        out_deg, in_deg = map(np.asarray, synapses.degrees(st.edges, eng.n))
         ax = np.floor(np.asarray(st.neurons.ax_elems)).astype(int)
         den = np.floor(np.asarray(st.neurons.den_elems)).astype(int)
         # elements keep growing between updates; allow the one-update slack
@@ -99,3 +98,45 @@ def test_step_does_not_rest_on_the_live_prefix(short_runs, monkeypatch):
                     jax.tree.leaves((end.neurons, end_recs))):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     assert int(prefix_recs.num_synapses[-1]) == live.size
+
+
+def test_update_over_live_extent_equals_whole_table(short_runs, monkeypatch):
+    """Updates with forced deletion give bitwise the same states and records
+    whether each pass of the update runs over the live extent of the edge
+    table or over the whole table (the ladder pinned to its top rung), on
+    one network and on a K = 2 ensemble."""
+    from repro.core import synapses
+    from repro.core.ensemble import EnsembleEngine
+    eng, st, _ = short_runs["fmm"]
+    # Every other neuron loses its elements, so at the next update it
+    # deletes its synapses on both sides, leaving holes in the prefix.
+    even = jnp.arange(eng.n) % 2 == 0
+    forced = st._replace(neurons=st.neurons._replace(
+        ax_elems=jnp.where(even, 0.0, st.neurons.ax_elems),
+        den_elems=jnp.where(even, 0.0, st.neurons.den_elems)))
+    # The second replica keeps its elements: another table from the first
+    # update on.
+    pair = jax.tree.map(lambda a, b: jnp.stack([a, b]), forced, st)
+    keys = jax.random.split(jax.random.key(7), 2)
+    steps = 3 * eng.msp_cfg.update_interval
+
+    def runs():  # new engines, so no trace at another ladder is reused
+        fresh = lambda: PlasticityEngine(eng.positions_np, eng.msp_cfg,
+                                         eng.fmm_cfg, eng.engine_cfg)
+        return (fresh().simulate(forced, keys[0], steps),
+                EnsembleEngine(fresh()).simulate(pair, keys, steps))
+
+    monkeypatch.setattr(synapses, "LIVE_EXTENTS", ())
+    want = runs()
+    monkeypatch.setattr(synapses, "LIVE_EXTENTS", (1024, 4096, 16384))
+    got = runs()
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    (_, recs), (_, pair_recs) = want
+    live = int(np.asarray(st.edges.valid).sum())
+    high = int(np.flatnonzero(np.asarray(st.edges.valid)).max()) + 1
+    # Deletion fired, and the passes ran below the table's size.
+    assert int(recs.num_synapses.min()) < live
+    assert int(pair_recs.num_synapses[:, 0].min()) < live
+    assert high < 16384 < st.edges.valid.shape[0]

@@ -181,6 +181,11 @@ def test_device_ops_name_their_layer_and_phase(method):
     assert seen["activity"] == ACTIVITY
     assert seen["update"] == (UPDATE - {"upward"} if method == "direct" else UPDATE)
     assert "activity/synaptic_input/while/body/live_block/" in text
+    # The update's passes over the table, one branch per extent of the
+    # ladder of a 32,768-slot table.
+    for phase in ("delete", "vacancies", "insert"):
+        for i, slots in enumerate((16384, 32768)):
+            assert f"update/{phase}/cond/branch_{i}_fun/live_extent_{slots}/" in text
     if method == "fmm":
         for scope in ("update/find/descent/", "update/find/leaf_resolve/"):
             assert scope in text

@@ -12,6 +12,7 @@ description made at import would make the other workers fail to collect.
 """
 
 import os
+import re
 
 import pytest
 import jax
@@ -111,3 +112,27 @@ def test_batched_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _batched_cases()[name]
     compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def _custom_call_memory(text):
+    """The HLO types of the Mosaic kernel's result and of each operand."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (.+?) \S+\(", text, re.M))
+    call = next(l for l in text.splitlines() if '"tpu_custom_call"' in l)
+    result, operands = re.match(r"\s*%\S+ = (.+?) custom-call\(([^)]*)\)",
+                                call).groups()
+    return [result] + [types[o.strip()] for o in operands.split(",")]
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_msp_update_operands_stay_in_hbm(one_chip, batched):
+    """The neuron update reads and writes its arrays in HBM (no `S(1)`,
+    XLA's mark for VMEM) between ops that XLA would otherwise stage in
+    VMEM, so its device time holds its whole traffic, under vmap too."""
+    fn, shapes = (_batched_cases() if batched else _kernel_cases())["msp_update"]
+
+    def around(x, *rest):
+        return [jnp.sin(o.astype(jnp.float32)) for o in fn(jnp.cos(x), *rest)]
+
+    text = _compile(around, one_chip, *shapes).as_text()
+    for t in _custom_call_memory(text):
+        assert "S(1)" not in t, t
