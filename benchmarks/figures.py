@@ -754,17 +754,20 @@ def fig_kernels(gauss_sizes=((512, 2048), (2048, 8192)),
     tests/test_kernels.py); a violation lands as an "error" key, which
     benchmarks.run surfaces as a nonzero exit (the bench-smoke gate).  Each
     tier also carries its analytic roofline numbers (flops_model.kernel_cost_*
-    against roofline.py's TPU-v5e peaks): t_compute_us / t_memory_us are what
-    the *native* kernel would cost on that machine, intensity = flops/byte.
+    against the TPU-v5e peaks in benchmarks/chip/peaks.json): t_compute_us /
+    t_memory_us are what the *native* kernel would cost on that machine,
+    intensity = flops/byte.
     """
     import jax
     import jax.numpy as jnp
-    from benchmarks import flops_model, roofline
+    from benchmarks import flops_model
     from repro.core import direct, expansions as ex
     from repro.core.msp import MSPConfig, init_neurons
     from repro.core import msp as msp_mod
     from repro.kernels import ops, ref
 
+    with open(os.path.join(os.path.dirname(__file__), "chip", "peaks.json")) as f:
+        v5e = json.load(f)["TPU v5 lite"]
     delta = 750.0 ** 2
     backend_label = "pallas-tpu" if jax.default_backend() == "tpu" \
         else "pallas-interpret"
@@ -796,8 +799,8 @@ def fig_kernels(gauss_sizes=((512, 2048), (2048, 8192)),
         entry["flops"] = cost["flops"]
         entry["hbm_bytes"] = cost["hbm_bytes"]
         entry["intensity_flops_per_byte"] = cost["flops"] / cost["hbm_bytes"]
-        entry["t_compute_us"] = cost["flops"] / roofline.PEAK_FLOPS * 1e6
-        entry["t_memory_us"] = cost["hbm_bytes"] / roofline.HBM_BW * 1e6
+        entry["t_compute_us"] = cost["flops"] / v5e["bf16_flops_per_s"] * 1e6
+        entry["t_memory_us"] = cost["hbm_bytes"] / v5e["hbm_bytes_per_s"] * 1e6
 
     out: Dict = {"backend": backend_label, "reps": reps,
                  "gaussian_nbody": {}, "m2l": {}, "msp_update": {}}

@@ -1,225 +1,19 @@
-"""Analytic per-cell FLOP / HBM-byte estimators for the roofline.
+"""Closed-form flop / HBM-byte counts of the three Pallas kernels.
 
-Why analytic: every layer stack lowers as `lax.scan`, and XLA's
-`cost_analysis()` counts a while-loop body ONCE (verified:
-scan=16.8 MF vs unrolled=134 MF for an 8-layer probe — see EXPERIMENTS.md
-§Roofline, methodology).  Rather than unroll 48-layer/400 B-param graphs just
-to please the cost model, compute and memory terms come from closed-form
-accounting (the same napkin math the perf loop uses), validated against
-`cost_analysis()` on probe configs whose scans have trip-count 1
-(test_roofline.py).  Collective bytes still come from the compiled HLO —
-XLA hoists the per-layer param gathers out of the loop, so the census is
-trip-count-correct there.
+`benchmarks.figures.fig_kernels` places each kernel tier on the TPU v5e
+roofline with these counts (peaks from `benchmarks/chip/peaks.json`), and
+tests/test_roofline.py checks them against `cost_analysis()`.  The chip
+benchmark counts the same bytes again in `benchmarks/chip/kernel_cost.py`;
+this module goes with the CPU figures when they are retired.
 
-Conventions
------------
-* flops count multiply+add as 2; causal attention is NOT halved (the
-  implementation computes masked full blocks — an honest accounting of what
-  runs, and itself a recorded §Perf lever);
-* train = fwd + 2x bwd + 1x remat recompute of fwd = 4x fwd flops;
-* HBM bytes: parameters are read once per pass (fwd, bwd, recompute) in bf16;
-  optimizer state (m, v, master: 3 x f32) is read+written once; gradients
-  f32 read+write; activations cross HBM at layer boundaries (bf16) plus the
-  attention/mamba inner working set; decode additionally reads the KV cache
-  once per token.
+The counts are of what actually runs: the gaussian kernel counts its padded
+8-lane matmul decomposition (not the 3-component textbook distance), m2l
+counts the unrolled mode-product FMAs, and msp counts the fused elementwise
+chain.  exp() counts as one flop.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Dict
-
-from repro.models.config import ModelConfig, ShapeConfig
-
-
-@dataclasses.dataclass
-class CellCost:
-    flops: float            # per device, per step
-    hbm_bytes: float        # per device, per step
-    model_flops: float      # useful (textbook) flops per device
-    detail: Dict[str, float]
-
-
-def _attn_dims(cfg: ModelConfig):
-    hd = cfg.resolved_head_dim
-    if cfg.use_mla:
-        # wq -> H*(nope+rope); dkv: D*r; kr: D*rope; uk/uv: r*H*128; wo
-        from repro.models.attention import MLA_QK_NOPE, MLA_V_DIM
-        qk = MLA_QK_NOPE + cfg.rope_head_dim
-        proj = (cfg.d_model * cfg.num_heads * qk
-                + cfg.d_model * cfg.kv_lora_rank
-                + cfg.d_model * cfg.rope_head_dim
-                + cfg.kv_lora_rank * cfg.num_heads * (MLA_QK_NOPE + MLA_V_DIM)
-                + cfg.num_heads * MLA_V_DIM * cfg.d_model)
-        score_dim = qk
-        v_dim = MLA_V_DIM
-    else:
-        proj = cfg.d_model * hd * (cfg.num_heads * 2 + cfg.num_kv_heads * 2)
-        score_dim = hd
-        v_dim = hd
-    return proj, score_dim, v_dim
-
-
-def _layer_flops_fwd(cfg: ModelConfig, tokens_per_seq: int, kv_len: int,
-                     batch: int) -> Dict[str, float]:
-    """Forward flops of ONE layer over (batch, tokens_per_seq) queries
-    attending to kv_len keys."""
-    t, s, b, d = tokens_per_seq, kv_len, batch, cfg.d_model
-    out: Dict[str, float] = {}
-    if cfg.family in ("dense", "vlm", "audio", "moe", "hybrid"):
-        proj, score_dim, v_dim = _attn_dims(cfg)
-        out["attn_proj"] = 2.0 * b * t * proj
-        out["attn_score"] = (2.0 * b * t * s * cfg.num_heads
-                             * (score_dim + v_dim))
-    if cfg.family in ("dense", "vlm", "audio"):
-        out["mlp"] = 2.0 * b * t * 3 * d * cfg.d_ff
-    if cfg.family in ("ssm", "hybrid"):
-        di = cfg.ssm_expand * d
-        nh = di // cfg.ssm_head_dim
-        n = cfg.ssm_state
-        q = min(cfg.ssm_chunk, t)
-        out["ssm_proj"] = 2.0 * b * t * d * (2 * di + 2 * n + nh) \
-            + 2.0 * b * t * di * d
-        out["ssm_conv"] = 2.0 * b * t * cfg.ssm_conv * (di + 2 * n)
-        # intra-chunk: CB^T (t*q*n) + apply (t*q*di); inter: states (t*n*di)
-        out["ssm_scan"] = 2.0 * b * t * (q * n + q * di + 2 * n * di)
-    return out
-
-
-def _moe_layer_flops(cfg: ModelConfig, tokens: float) -> float:
-    f = 2.0 * tokens * 3 * cfg.d_model * cfg.moe_d_ff
-    routed = f * cfg.top_k
-    shared = f * cfg.num_shared_experts
-    router = 2.0 * tokens * cfg.d_model * cfg.num_experts
-    return routed + shared + router
-
-
-def param_count(cfg: ModelConfig) -> Dict[str, float]:
-    """Analytic parameter census (validated vs the abstract tree)."""
-    import jax
-    from repro.launch import steps as S
-    params = S.abstract_params(cfg)
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-    total = expert = embed = 0
-    for path, leaf in flat:
-        names = [str(getattr(p, "key", p)) for p in path]
-        sz = 1
-        for dd in leaf.shape:
-            sz *= dd
-        total += sz
-        if "moe" in names and leaf.ndim >= 3:
-            expert += sz
-        if names[-1] == "table" or "head" in names:
-            embed += sz
-    active = total - expert
-    if cfg.num_experts:
-        active += expert * (cfg.top_k + cfg.num_shared_experts * 0.0) \
-            / cfg.num_experts
-    return {"total": float(total), "expert": float(expert),
-            "embed": float(embed), "active": float(active)}
-
-
-# Measured train-step flop multipliers over one forward pass (remat =
-# nothing_saveable + flash custom-vjp recompute), from the 1-layer probes in
-# tests/test_roofline.py: backward-with-remat / forward.
-TRAIN_MULT = {"dense": 3.19, "vlm": 3.19, "moe": 3.32, "ssm": 3.16,
-              "hybrid": 3.61, "audio": 3.77}
-
-
-def cell_cost(cfg: ModelConfig, shape: ShapeConfig, chips: int) -> CellCost:
-    b, s = shape.global_batch, shape.seq_len
-    d, l, v = cfg.d_model, cfg.num_layers, cfg.vocab_size
-    pc = param_count(cfg)
-
-    if shape.kind == "train":
-        t, kv_len, passes = s, s, TRAIN_MULT[cfg.family]
-    elif shape.kind == "prefill":
-        t, kv_len, passes = s, s, 1.0
-    else:
-        t, kv_len, passes = 1, s, 1.0
-
-    # ---- flops -------------------------------------------------------------
-    per_layer = _layer_flops_fwd(cfg, t, kv_len, b)
-    layer_sum = sum(per_layer.values())
-    flops = layer_sum * l
-    if cfg.family == "moe":
-        moe_layers = (l - cfg.first_dense_layers) // cfg.moe_layer_step
-        dense_layers = l - moe_layers
-        mlp_dense = 2.0 * b * t * 3 * d * cfg.d_ff
-        flops = (per_layer["attn_proj"] + per_layer["attn_score"]) * l \
-            + mlp_dense * dense_layers \
-            + _moe_layer_flops(cfg, b * t) * moe_layers
-    if cfg.family == "hybrid":
-        n_sites = l // cfg.shared_attn_every if cfg.shared_attn_every else 0
-        # mamba on all L layers + shared attn+mlp on the sites
-        flops = (per_layer["ssm_proj"] + per_layer["ssm_conv"]
-                 + per_layer["ssm_scan"]) * l \
-            + (per_layer["attn_proj"] + per_layer["attn_score"]
-               + 2.0 * b * t * 3 * d * cfg.d_ff) * n_sites
-    head = 2.0 * b * t * d * v
-    flops = (flops + head) * passes
-    if cfg.family == "ssm":
-        attn_layers = 0
-    elif cfg.family == "hybrid":
-        attn_layers = l // cfg.shared_attn_every if cfg.shared_attn_every else 0
-    else:
-        attn_layers = l
-    model_flops = (2.0 if passes == 1.0 else 6.0) * pc["active"] * b * t \
-        + (2.0 * b * t * kv_len * cfg.num_heads * cfg.resolved_head_dim * 2
-           * (3.0 if passes > 1 else 1.0) * attn_layers)
-
-    # ---- HBM bytes ----------------------------------------------------------
-    p_bytes = pc["total"] * 2.0
-    act_boundary = b * t * d * 2.0 * l
-    if shape.kind == "train":
-        hbm = (p_bytes * 3.0                    # fwd + recompute + bwd reads
-               + pc["total"] * 4.0 * 2.0        # grads f32 write+read
-               + pc["total"] * 12.0 * 2.0       # opt m,v,master read+write
-               + act_boundary * 4.0             # save + reload (+grad acts)
-               + b * t * v * 4.0 * 2.0)         # logits f32 write+read
-    elif shape.kind == "prefill":
-        cache_bytes = _cache_bytes(cfg, b, s)
-        hbm = p_bytes + act_boundary * 2.0 + cache_bytes \
-            + b * t * v * 4.0
-    else:
-        cache_bytes = _cache_bytes(cfg, b, s)
-        hbm = p_bytes * (pc["active"] / pc["total"] if cfg.num_experts
-                         else 1.0) \
-            + cache_bytes + b * v * 4.0
-    return CellCost(flops=flops / chips, hbm_bytes=hbm / chips,
-                    model_flops=model_flops / chips,
-                    detail={k: val * l * passes / chips
-                            for k, val in per_layer.items()})
-
-
-def _cache_bytes(cfg: ModelConfig, b: int, s: int) -> float:
-    if cfg.family == "ssm":
-        di = cfg.ssm_expand * cfg.d_model
-        nh = di // cfg.ssm_head_dim
-        return (b * nh * cfg.ssm_state * cfg.ssm_head_dim * 4.0
-                + b * (cfg.ssm_conv - 1) * (di + 2 * cfg.ssm_state) * 2.0) \
-            * cfg.num_layers
-    if cfg.family == "hybrid":
-        ssm = _cache_bytes(dataclasses.replace(cfg, family="ssm"), b, s)
-        n_sites = cfg.num_layers // cfg.shared_attn_every
-        kv = 2.0 * b * s * cfg.num_kv_heads * cfg.resolved_head_dim * 2.0 \
-            * n_sites
-        return ssm + kv
-    if cfg.use_mla:
-        return b * s * (cfg.kv_lora_rank + cfg.rope_head_dim) * 2.0 \
-            * cfg.num_layers
-    return 2.0 * b * s * cfg.num_kv_heads * cfg.resolved_head_dim * 2.0 \
-        * cfg.num_layers
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel costs (benchmarks.figures.fig_kernels roofline legs)
-# ---------------------------------------------------------------------------
-#
-# Closed-form flop/byte accounting of the three repro.kernels hot spots, in
-# the same honest what-actually-runs spirit as the cell costs above: the
-# gaussian kernel counts its padded 8-lane matmul decomposition (not the
-# 3-component textbook distance), m2l counts the unrolled mode-product FMAs,
-# and msp counts the fused elementwise chain.  exp() counts as one flop.
 
 
 def kernel_cost_gaussian_nbody(n: int, m: int) -> Dict[str, float]:

@@ -6,13 +6,10 @@ with the properties a 1000-node run needs from the *per-process* layer:
   * atomic publish (write to tmp dir, fsync, rename) — a crash mid-write can
     never corrupt the latest checkpoint;
   * async mode: the device->host copy happens synchronously (cheap), the disk
-    write happens on a background thread so training overlaps I/O;
+    write happens on a background thread so the simulation overlaps I/O;
   * retention (`keep`) + monotonically named steps + `latest_step()`;
   * layout: one .npz per save with path-keyed arrays + a JSON manifest
     (dtypes/shapes/step) used for validation on restore.
-
-At fleet scale each process saves only its parameter shards (addressable
-devices); orchestration of who-writes-what is runtime/failures.py's job.
 
 Probe interaction (DESIGN.md §12): core/probes.ProbeState is an ordinary
 pytree (NamedTuple holding a dict of chunk buffers), so checkpointing a

@@ -1,7 +1,8 @@
-"""Production mesh definitions.
+"""Device meshes of the simulator: the replica, neuron-shard and 2-D sweep
+layouts, over the axes declared in sharding/rules.AXIS_CONTRACTS.
 
 Functions, not module-level constants: importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before first jax init; smoke
+jax device state (a dry-run sets XLA_FLAGS before first jax init; smoke
 tests must keep seeing 1 device).
 """
 
@@ -10,19 +11,6 @@ from __future__ import annotations
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over whatever devices exist (tests / examples)."""
-    devs = np.array(jax.devices()[: data * model]).reshape(data, model)
-    return Mesh(devs, ("data", "model"))
 
 
 def make_ensemble_mesh(num_devices: int | None = None, axis: str = "ensemble") -> Mesh:

@@ -1,24 +1,24 @@
-"""Sharding rules: parameter/optimizer/cache/batch PartitionSpecs per mesh.
+"""PartitionSpecs of the simulator's sharded programs, and its axis contracts.
 
-Scheme (MaxText-style 2-axis logical layout, extended with a "pod" axis):
+Two named mesh axes, both declared in `AXIS_CONTRACTS`:
 
-  fsdp axis  = ("pod", "data")   parameters, optimizer moments, activations'
-                                 batch dim  (ZeRO-3: every weight matrix
-                                 shards its d_model-ish dim over fsdp)
-  tensor axis = "model"          heads / ffn / experts / vocab / ssm-heads
+  "data"      the neuron-shard axis of core/distributed.py (the paper's MPI
+              ranks): exact raw-sum psums and edge-table/request gathers;
+  "ensemble"  the replica (and serve-slot) axis of core/ensemble.py and
+              repro/serve: pure batching, no collective.
 
-Rules are name+rank based over the parameter pytree paths (plain dicts), so
-they apply to any architecture in the zoo without per-model annotations.
-Divisibility is checked and falls back to replication on that dim (recorded —
-the dry-run prints every fallback so sharding gaps are visible, not silent).
+The specs below place the engines' state, records and probe buffers on
+those axes: `ensemble_spec` and `serve_round_specs` (replica axis only),
+`pyramid_input_spec`, `descent_map_spec`, `find_request_spec` and
+`probe_state_spec` (data axis, DESIGN.md §9, §10, §12), and `sweep2d_spec`
+/ `ensemble_sharded_spec` (the 2-D replica x data sweep, §7).
 """
 from __future__ import annotations
 
-from typing import Any, Sequence, Tuple
+from typing import Any
 
 import jax
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 PyTree = Any
 
@@ -48,156 +48,6 @@ AXIS_CONTRACTS = {
         "collectives": frozenset(),
     },
 }
-
-
-def fsdp_axes(mesh: Mesh) -> Tuple[str, ...]:
-    return tuple(a for a in ("pod", "data") if a in mesh.shape)
-
-
-def _fits(mesh: Mesh, dim: int, axes) -> bool:
-    if axes is None or dim <= 0:
-        return True
-    size = int(np.prod([mesh.shape[a] for a in
-                        ((axes,) if isinstance(axes, str) else axes)]))
-    return dim % size == 0
-
-
-def _spec(mesh: Mesh, shape: Sequence[int], wanted: Sequence) -> P:
-    """Drop axis assignments that don't divide the dim (with fallback)."""
-    return P(*[a if _fits(mesh, d, a) else None
-               for d, a in zip(shape, wanted)])
-
-
-# -- parameters ---------------------------------------------------------------
-
-def param_spec(mesh: Mesh, path, leaf) -> P:
-    """PartitionSpec for one parameter; `path` is a tree_flatten_with_path
-    key path, `leaf` an array (or ShapeDtypeStruct)."""
-    fsdp = fsdp_axes(mesh)
-    name = str(getattr(path[-1], "key", path[-1]))
-    shape = leaf.shape
-    rank = len(shape)
-
-    def build(*tail):
-        """Pad with leading None for stacked-layer dims."""
-        lead = (None,) * (rank - len(tail))
-        return _spec(mesh, shape, lead + tail)
-
-    if name == "table":                       # embedding (V, d)
-        return build("model", fsdp)
-    if name in ("wq", "wk", "wv", "wi", "wg", "in_proj", "w_dkv", "w_kr"):
-        # experts (.., E, d, f) vs dense (.., d, f)
-        if name in ("wi", "wg") and rank >= 3 and _looks_like_experts(path):
-            return build("model", fsdp, None)
-        return build(fsdp, "model")
-    if name == "wo":
-        if rank >= 3 and _looks_like_experts(path):
-            return build("model", None, fsdp)
-        return build("model", fsdp)
-    if name == "out_proj":
-        return build("model", fsdp)
-    if name == "w":                            # head / frontend (d_in, d_out)
-        return build(fsdp, "model")
-    if name == "router":
-        return build(fsdp, None)
-    if name in ("w_uk", "w_uv"):               # (r, H, n)
-        return build(None, "model", None)
-    if name == "conv_w":                       # (W, C)
-        return build(None, "model")
-    if name in ("a_log", "d_skip", "dt_bias"):  # (H,)
-        return build("model")
-    if name in ("bq", "bk", "bv"):             # (H*hd,)
-        return build("model")
-    # norms scales, small biases: replicated
-    return P(*([None] * rank))
-
-
-def _looks_like_experts(path) -> bool:
-    return any(str(getattr(p, "key", p)) in ("moe",) for p in path)
-
-
-def _strip_axes(spec: P, strip: set) -> P:
-    cleaned = []
-    for part in spec:
-        if part is None:
-            cleaned.append(None)
-        elif isinstance(part, (tuple, list)):
-            kept = tuple(a for a in part if a not in strip)
-            cleaned.append(kept if kept else None)
-        else:
-            cleaned.append(None if part in strip else part)
-    return P(*cleaned)
-
-
-def param_spec_serve(mesh: Mesh, path, leaf) -> P:
-    """Serving-posture parameter sharding: tensor-parallel over "model" only,
-    REPLICATED over the fsdp axes.
-
-    Training shards weights over fsdp (ZeRO) because optimizer state forces
-    it; a serving step has no optimizer, and FSDP weights cost one all-gather
-    per layer per decoded token (measured: ~80 MB f32/step at qwen2 scale —
-    EXPERIMENTS.md §Perf LM-cell-2, iteration 2).  Callers fall back to the
-    training spec when the model-only shards don't fit HBM (llama4-400b)."""
-    return _strip_axes(param_spec(mesh, path, leaf), set(fsdp_axes(mesh)))
-
-
-def param_spec_dp(mesh: Mesh, path, leaf) -> P:
-    """DP-over-model training posture: weights ZeRO-sharded over fsdp axes,
-    REPLICATED over "model"; the model axis carries batch shards instead.
-
-    16-way tensor parallelism costs one (B_loc, S, d) psum per contraction
-    per layer — the census showed this dominating EVERY train cell whose
-    state doesn't actually need model sharding (qwen2: 2.28 s collective vs
-    0.089 s compute).  When the optimizer state fits at fsdp-only sharding
-    and the global batch divides the whole mesh, pure DP eliminates the
-    per-layer collectives entirely; gradients reduce once per step
-    (EXPERIMENTS.md §Perf, LM-global iteration)."""
-    return _strip_axes(param_spec(mesh, path, leaf), {"model"})
-
-
-# -- activations / batches ----------------------------------------------------
-
-def batch_spec(mesh: Mesh, batch: int, include_model: bool = False) -> P:
-    """Shard the batch over (pod, data[, model]) by divisibility fallback.
-
-    include_model=True is the DP-over-model posture (see param_spec_dp):
-    the batch also spans the "model" axis because nothing else uses it."""
-    fsdp = fsdp_axes(mesh)
-    if include_model and _fits(mesh, batch, fsdp + ("model",)):
-        return P(fsdp + ("model",))
-    if _fits(mesh, batch, fsdp):
-        return P(fsdp)
-    if _fits(mesh, batch, "data"):
-        return P("data")
-    return P(None)
-
-
-def data_spec(mesh: Mesh, shape: Sequence[int],
-              include_model: bool = False) -> P:
-    """(B, S) token batches / (B, S, F) feature batches."""
-    b = batch_spec(mesh, shape[0], include_model)
-    return P(*(list(b) + [None] * (len(shape) - 1)))
-
-
-# -- decode caches -------------------------------------------------------------
-
-def cache_spec(mesh: Mesh, path, leaf) -> P:
-    """KV caches (L, B, S, KV, hd) / (L, B, S, r): batch over fsdp, SEQ over
-    "model" (decode attention's softmax/reductions over the sharded seq dim
-    lower to psums — flash-decoding's partial-softmax pattern, derived by
-    SPMD).  SSM states (L, B, H, N, P): heads over "model"."""
-    fsdp = fsdp_axes(mesh)
-    name = str(getattr(path[-1], "key", path[-1]))
-    shape = leaf.shape
-    if name in ("k", "v"):                     # (L, B, S, KV, hd)
-        return _spec(mesh, shape, (None, fsdp, "model", None, None))
-    if name in ("c", "kr"):                    # (L, B, S, r)
-        return _spec(mesh, shape, (None, fsdp, "model", None))
-    if name == "ssm":                          # (L, B, H, N, P)
-        return _spec(mesh, shape, (None, fsdp, "model", None, None))
-    if name == "conv":                         # (L, B, W-1, C)
-        return _spec(mesh, shape, (None, fsdp, None, "model"))
-    return P(*([None] * len(shape)))
 
 
 # -- ensemble replica axis ------------------------------------------------------
@@ -333,17 +183,3 @@ def ensemble_sharded_spec(tree: PyTree, ensemble_axis: str = "ensemble",
     return jax.tree.map(
         lambda x: sweep2d_spec(ensemble_axis, data_axis, x.ndim)
         if x.ndim >= 2 else P(ensemble_axis), tree)
-
-
-# -- whole-state helpers --------------------------------------------------------
-
-def tree_specs(mesh: Mesh, tree: PyTree, spec_fn) -> PyTree:
-    flat = jax.tree_util.tree_flatten_with_path(tree)
-    return jax.tree_util.tree_unflatten(
-        flat[1], [spec_fn(mesh, path, leaf) for path, leaf in flat[0]])
-
-
-def tree_shardings(mesh: Mesh, tree: PyTree, spec_fn) -> PyTree:
-    return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        tree_specs(mesh, tree, spec_fn),
-                        is_leaf=lambda x: isinstance(x, P))

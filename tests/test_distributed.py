@@ -1,8 +1,7 @@
 """Multi-device tests (subprocess: needs forced host device count).
 
 These exercise the paper's distribution scheme: pyramid branch exchange
-(psum exactness), the sharded simulation loop, and sharded LM training —
-on 8 fake CPU devices.
+(psum exactness) and the sharded simulation loop — on 8 fake CPU devices.
 """
 import os
 import subprocess
@@ -70,36 +69,6 @@ syn = int(np.asarray(recs.num_synapses)[-1])
 assert np.isfinite(ca) and ca > 0.1, ca
 assert syn > 50, syn
 print("SIM_OK", ca, syn)
-
-# --- 3. sharded LM train step (2x4 mesh, pjit path) ----------------------
-from repro import configs
-from repro.launch import steps as S
-from repro.launch.mesh import make_host_mesh
-from repro.optim import adamw
-from repro.models import model as M
-from repro.launch.steps import TrainState
-from repro.data.pipeline import DataConfig, make_batch
-
-cfg = configs.get("qwen3-8b").reduced(layers=2, d_model=64, vocab=128)
-opt_cfg = adamw.OptConfig(warmup_steps=2, total_steps=10)
-mesh2 = make_host_mesh(data=2, model=4)
-params = M.init_params(jax.random.key(0), cfg)
-state = TrainState(params=params, opt=adamw.init(params, opt_cfg),
-                   step=jnp.zeros((), jnp.int32))
-state_sh = S.state_shardings(mesh2, cfg, opt_cfg)
-state = jax.device_put(state, state_sh)
-# No explicit in_shardings: the state is already committed to state_sh by
-# device_put, and jax 0.4.x mis-resolves a NamedTuple sharding tree passed
-# to jit (P(None) vs the committed P("model") on bias leaves).
-step_fn = jax.jit(S.make_train_step(cfg, opt_cfg, remat=False, mesh=mesh2))
-losses = []
-with mesh2:
-    for i in range(6):
-        batch = make_batch(cfg, DataConfig(seed=1), i, 8, 32)
-        state, m = step_fn(state, batch)
-        losses.append(float(m["loss"]))
-assert losses[-1] < losses[0], losses
-print("LM_SHARDED_OK", losses[0], losses[-1])
 '''
 
 
@@ -112,4 +81,3 @@ def test_distributed_subprocess():
     assert res.returncode == 0, res.stdout[-3000:] + res.stderr[-3000:]
     assert "PYRAMID_OK" in res.stdout
     assert "SIM_OK" in res.stdout
-    assert "LM_SHARDED_OK" in res.stdout
